@@ -64,6 +64,7 @@ from __future__ import annotations
 import argparse
 import time
 
+import jax
 import numpy as np
 
 from benchmarks.bench_coldstart import novel_apps
@@ -262,12 +263,7 @@ def kernel_threshold_microbench(f, smoke: bool) -> dict:
         best = min(_time_predict(target, X[:n]) for _ in range(repeat))
         numpy_us[n] = round(best / n * 1e6, 3)
     kernel_us = None
-    try:
-        import jax
-        on_tpu = jax.default_backend() == "tpu"
-    except Exception:
-        on_tpu = False
-    if on_tpu and target.gbdt is not None:
+    if jax.default_backend() == "tpu" and target.gbdt is not None:
         from repro.kernels import ops
         kernel_us = {}
         for n in sizes:

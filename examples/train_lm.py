@@ -19,6 +19,7 @@ from repro.core.model_apps import derive_app
 from repro.data.pipeline import DataConfig, SyntheticLM
 from repro.dist.fault_tolerance import (FailureInjector, RunnerConfig,
                                         TrainingRunner)
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import model
 from repro.optim import adamw
 from repro.train.step import make_train_step
@@ -34,6 +35,7 @@ def main():
     ap.add_argument("--vocab", type=int, default=1024)
     ap.add_argument("--inject-failure", action="store_true", default=True)
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = dataclasses.replace(
         get_config("smollm-360m"),

@@ -1,6 +1,6 @@
 #!/usr/bin/env bash
 # One-command gate for every PR: tier-1 tests, docs link check, and fast
-# benchmark smokes (CPU / Pallas-interpret mode — no accelerator required).
+# benchmark smokes (CPU, Pallas kernels in interpret mode).
 set -euo pipefail
 cd "$(dirname "$0")/.."
 export PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}"

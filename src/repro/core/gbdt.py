@@ -67,21 +67,27 @@ class GBDTModel:
     feature_names: Optional[Sequence[str]] = None
 
     # ------------------------------------------------------------------ #
+    def leaf_indices(self, X: np.ndarray) -> np.ndarray:
+        """(n, n_trees) leaf index of every row in every tree: bit ``l`` is
+        ``x[f_l] > t_l``. The Pallas kernel computes the same on a TPU."""
+        X = np.asarray(X, dtype=np.float64)
+        depth = self.feats.shape[1]
+        bits = X[:, self.feats] > self.thresholds[None, :, :]
+        return bits @ (1 << np.arange(depth)).astype(np.int64)
+
+    def predict_from_leaves(self, leaf_idx: np.ndarray) -> np.ndarray:
+        """Sum the leaf values that ``leaf_idx`` selects, in float64. Both
+        the numpy and the kernel path end here, so equal indices give
+        bit-identical predictions. The indices are made C-contiguous first:
+        the sum's rounding follows the memory order of the selected values,
+        and arrays copied back from a device may come in column order."""
+        leaf_idx = np.ascontiguousarray(leaf_idx)
+        contrib = self.leaves[np.arange(self.leaves.shape[0]), leaf_idx]
+        return self.base + contrib.sum(axis=1)
+
     def predict(self, X: np.ndarray) -> np.ndarray:
         """Vectorized ensemble prediction. X: (n, n_features) → (n,)."""
-        X = np.asarray(X, dtype=np.float64)
-        n_trees, depth = self.feats.shape
-        # (n, n_trees, depth): comparison bits
-        gathered = X[:, self.feats]                       # (n, n_trees, depth)
-        bits = gathered > self.thresholds[None, :, :]
-        weights = (1 << np.arange(depth)).astype(np.int64)
-        leaf_idx = bits @ weights                          # (n, n_trees)
-        contrib = np.take_along_axis(
-            self.leaves[None, :, :].repeat(X.shape[0], axis=0),
-            leaf_idx[:, :, None],
-            axis=2,
-        )[..., 0]
-        return self.base + contrib.sum(axis=1)
+        return self.predict_from_leaves(self.leaf_indices(X))
 
     # ------------------------------------------------------------------ #
     def feature_importance(self, normalize: bool = True) -> np.ndarray:
@@ -99,18 +105,9 @@ class GBDTModel:
 
     def staged_rmse(self, X: np.ndarray, y: np.ndarray) -> np.ndarray:
         """RMSE after each boosting stage (for iteration-count diagnostics)."""
-        X = np.asarray(X, dtype=np.float64)
         y = np.asarray(y, dtype=np.float64)
-        n_trees, depth = self.feats.shape
-        gathered = X[:, self.feats]
-        bits = gathered > self.thresholds[None, :, :]
-        weights = (1 << np.arange(depth)).astype(np.int64)
-        leaf_idx = bits @ weights
-        contrib = np.take_along_axis(
-            self.leaves[None, :, :].repeat(X.shape[0], axis=0),
-            leaf_idx[:, :, None],
-            axis=2,
-        )[..., 0]                                          # (n, n_trees)
+        contrib = self.leaves[np.arange(self.leaves.shape[0]),
+                              self.leaf_indices(X)]        # (n, n_trees)
         cum = self.base + np.cumsum(contrib, axis=1)       # (n, n_trees)
         err = cum - y[:, None]
         return np.sqrt(np.mean(err ** 2, axis=0))

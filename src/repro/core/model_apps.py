@@ -214,16 +214,12 @@ def derive_counters(cfg: ModelConfig, phase: str,
 def aot_counters(compiled, n_chips: int = 1
                  ) -> Optional[tuple[float, float]]:
     """Optional AOT refinement: per-chip (flops, bytes) from an XLA
-    compiled artifact's cost analysis. Returns ``None`` whenever the
-    artifact carries no usable cost data (e.g. no compiler on this host)
-    — callers fall back to the analytic terms."""
-    try:
-        from repro.roofline.analysis import costs_of
-        c = costs_of(compiled)
-        flops = float(c.get("flops", 0.0) or 0.0)
-        nbytes = float(c.get("bytes accessed", 0.0) or 0.0)
-    except Exception:
-        return None
+    compiled artifact's cost analysis. Returns ``None`` when the artifact
+    carries no usable cost data (the backend reports no cost analysis, or
+    zero flops or bytes) — callers fall back to the analytic terms."""
+    from repro.roofline.analysis import costs_of
+    c = costs_of(compiled)
+    flops, nbytes = c["flops"], c["bytes"]
     if flops <= 0.0 or nbytes <= 0.0:
         return None
     return flops / n_chips, nbytes / n_chips

@@ -16,10 +16,11 @@ decision from cache:
   ground-truth analogues for the oracle policy (memoized testbed sweeps).
 
 Large batches route through the Pallas one-hot-matmul GBDT kernel
-(:mod:`repro.kernels.gbdt_predict`); on hosts without a TPU the service
-falls back to the vectorized numpy path (bit-identical to calling the
-predictor directly), so results are reproducible everywhere. Set
-``use_kernel=True`` to force the kernel (interpret mode on CPU).
+(:mod:`repro.kernels.gbdt_predict`) when the default backend is a TPU; a
+kernel failure there raises. Every other batch, and every batch on other
+backends, takes the vectorized numpy path (bit-identical to calling the
+predictor directly). Set ``use_kernel=True`` to force the kernel
+(interpret mode on CPU).
 
 :class:`ServiceStats` counts builds vs hits — the scheduling benchmarks
 assert at most one table build per distinct app.
@@ -75,6 +76,7 @@ import difflib
 import os
 from typing import Optional, Sequence
 
+import jax
 import numpy as np
 
 from .correlate import CorrelationIndex
@@ -240,12 +242,8 @@ class ServiceStats:
                 f"invalidations={self.invalidations}")
 
 
-def _tpu_available() -> bool:
-    try:
-        import jax
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
 
 
 class PredictionService:
@@ -689,7 +687,7 @@ class PredictionService:
         if use == "auto":
             use = (target.gbdt is not None
                    and X.shape[0] >= self.kernel_min_rows
-                   and _tpu_available())
+                   and _on_tpu())
         elif use:
             use = target.gbdt is not None
         if use:

@@ -1,1 +1,2 @@
-"""Pallas TPU kernels (validated via interpret=True on CPU) + jnp oracles."""
+"""Pallas TPU kernels (compiled on a TPU, interpreted on the CPU) + jnp
+oracles."""

@@ -1,32 +1,32 @@
-"""Pallas TPU batched oblivious-tree ensemble inference.
+"""Pallas TPU batched oblivious-tree ensemble traversal.
 
 The scheduler's hot loop (Algorithm 1) evaluates every queued job against
-every supported clock pair for two GBDT ensembles — (jobs × 64 clocks ×
-2·1200 trees) predictions per scheduling tick. On GPU this is a
-pointer-chasing tree walk; the TPU-native formulation turns both gathers
-into one-hot **matmuls** so the MXU does the traversal:
+every supported clock pair for two GBDT ensembles. On a GPU this is a
+pointer-chasing tree walk; here every tile is 2-D (rows on sublanes, trees
+on lanes) and the MXU does the feature gather:
 
-  x_gathered[n, t, d] = Σ_f X[n, f] · onehot_feats[t, d, f]      (MXU)
-  bits = x_gathered > thresholds ;  idx = Σ_d bits·2^d
-  pred[n] += Σ_c onehot(idx)[n, t, c] · leaves[t, c]             (MXU)
+  for each depth level d:
+    g_d[n, t] = Σ_f X[n, f] · onehot[d, f, t]          (MXU, f32 HIGHEST)
+    idx[n, t] += (g_d[n, t] > thr[d, t]) · 2^d          (VPU)
 
-Oblivious trees make this possible: a depth-d tree is d (feature, threshold)
-pairs + a 2^d leaf table, so "traversal" is data-independent — exactly the
-property CatBoost exploits for SIMD scoring on CPU (DESIGN.md hardware
-adaptation note).
+Oblivious trees make this possible: a depth-d tree is d (feature,
+threshold) pairs plus a 2^d leaf table, so traversal is data-independent —
+the property CatBoost exploits for SIMD scoring on CPU. The gather matmul
+runs at ``Precision.HIGHEST`` so that one-hot × X reproduces X exactly in
+f32: a gathered value rounded below f32 could cross a threshold and pick
+another leaf.
 
-Grid: (row blocks, tree blocks), tree dim innermost and sequential,
-accumulating into a VMEM scratch; BlockSpecs stage (BN, F) row tiles and
-(BT·D, F) one-hot tiles.
+The kernel returns the (rows, trees) leaf indices. The host sums the
+selected leaf values in float64 with the numpy path's own code
+(:meth:`repro.core.gbdt.GBDTModel.predict_from_leaves`), so equal indices
+give bit-identical predictions: an f32 leaf sum differs from it by ~1e-7
+relative, enough to flip near-tied clock choices and change a schedule.
 
-Routing: the prediction service only sends batches of at least
-``repro.core.prediction_service.DEFAULT_KERNEL_MIN_ROWS`` rows here (env
-override ``REPRO_GBDT_KERNEL_MIN_ROWS``; ≤ 0 routes everything) — the
-threshold sits where the numpy ensemble leaves its cache-resident regime,
-measured by the ``kernel_threshold`` microbench in
-``benchmarks/bench_decide.py``. Single-ladder builds stay on numpy; the
-batched admission-time prefetch (PR 6) is the caller that reaches kernel
-scale.
+Routing: :class:`repro.core.prediction_service.PredictionService` sends
+predictor batches of at least ``DEFAULT_KERNEL_MIN_ROWS`` rows here when
+the backend is a TPU (env override ``REPRO_GBDT_KERNEL_MIN_ROWS``; ≤ 0
+routes everything). Single-ladder builds stay on numpy; the batched
+admission-time prefetch is the caller that reaches kernel scale.
 """
 from __future__ import annotations
 
@@ -35,70 +35,40 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 BN = 256   # rows per block
-BT = 64    # trees per block
+BT = 128   # trees per block (one lane width)
 
 
-def _kernel(x_ref, oh_ref, thr_ref, leaves_ref, out_ref, acc_ref, *,
-            depth: int, n_tb: int):
-    ti = pl.program_id(1)
-
-    @pl.when(ti == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    x = x_ref[...].astype(jnp.float32)                     # (BN, F)
-    oh = oh_ref[...].astype(jnp.float32)                   # (BT*D, F)
-    thr = thr_ref[...].astype(jnp.float32)                 # (BT, D)
-    leaves = leaves_ref[...].astype(jnp.float32)           # (BT, 2**D)
-
-    bt = thr.shape[0]
-    # gather-as-matmul: (BN, F) x (F, BT*D) -> (BN, BT, D)
-    g = jax.lax.dot_general(x, oh, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-    g = g.reshape(x.shape[0], bt, depth)
-    bits = (g > thr[None]).astype(jnp.float32)             # (BN, BT, D)
-    w = (2.0 ** jnp.arange(depth))[None, None, :]
-    idx = jnp.sum(bits * w, axis=-1).astype(jnp.int32)     # (BN, BT)
-    # leaf lookup as one-hot matmul over the leaf axis
-    n_leaves = leaves.shape[1]
-    onehot_leaf = (idx[..., None] ==
-                   jnp.arange(n_leaves)[None, None, :]).astype(jnp.float32)
-    contrib = jnp.sum(onehot_leaf * leaves[None], axis=(1, 2))   # (BN,)
-    acc_ref[...] += contrib[:, None]
-
-    @pl.when(ti == n_tb - 1)
-    def _fin():
-        out_ref[...] = acc_ref[...].astype(out_ref.dtype)
+def _kernel(x_ref, oh_ref, thr_ref, idx_ref, *, depth: int):
+    x = x_ref[...]                                         # (BN, F)
+    idx = jnp.zeros(idx_ref.shape, jnp.int32)              # (BN, BT)
+    for d in range(depth):
+        g = jax.lax.dot_general(
+            x, oh_ref[d], (((1,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)            # (BN, BT)
+        idx = idx + jnp.where(g > thr_ref[d:d + 1, :], 1 << d, 0)
+    idx_ref[...] = idx
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "bn", "bt"))
-def gbdt_predict(X, feats_onehot, thresholds, leaves, base,
-                 interpret: bool = False, bn: int = BN, bt: int = BT):
-    """X: (n, F); feats_onehot: (T, D, F) fp32; thresholds: (T, D);
-    leaves: (T, 2**D); base: scalar. n % bn == 0, T % bt == 0 (ops pads).
-    Returns (n,) fp32."""
+def gbdt_leaf_indices(X, feats_onehot, thresholds, interpret: bool = False,
+                      bn: int = BN, bt: int = BT):
+    """X: (n, F) fp32; feats_onehot: (D, F, T) fp32; thresholds: (D, T).
+    n % bn == 0, T % bt == 0 (ops pads). Returns (n, T) int32 leaf
+    indices."""
     n, F = X.shape
-    T, depth = thresholds.shape
-    n_nb = n // bn
-    n_tb = T // bt
-    oh = feats_onehot.reshape(T * depth, F)
-
-    kernel = functools.partial(_kernel, depth=depth, n_tb=n_tb)
-    out = pl.pallas_call(
-        kernel,
-        grid=(n_nb, n_tb),
+    depth, T = thresholds.shape
+    return pl.pallas_call(
+        functools.partial(_kernel, depth=depth),
+        grid=(n // bn, T // bt),
         in_specs=[
             pl.BlockSpec((bn, F), lambda ni, ti: (ni, 0)),
-            pl.BlockSpec((bt * depth, F), lambda ni, ti: (ti, 0)),
-            pl.BlockSpec((bt, depth), lambda ni, ti: (ti, 0)),
-            pl.BlockSpec((bt, leaves.shape[1]), lambda ni, ti: (ti, 0)),
+            pl.BlockSpec((depth, F, bt), lambda ni, ti: (0, 0, ti)),
+            pl.BlockSpec((depth, bt), lambda ni, ti: (0, ti)),
         ],
-        out_specs=pl.BlockSpec((bn, 1), lambda ni, ti: (ni, 0)),
-        out_shape=jax.ShapeDtypeStruct((n, 1), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((bn, 1), jnp.float32)],
+        out_specs=pl.BlockSpec((bn, bt), lambda ni, ti: (ni, ti)),
+        out_shape=jax.ShapeDtypeStruct((n, T), jnp.int32),
         interpret=interpret,
-    )(X, oh, thresholds, leaves)
-    return out[:, 0] + base
+    )(X, feats_onehot, thresholds)

@@ -1,9 +1,10 @@
 """jit'd public wrappers around the Pallas kernels.
 
 Handles: layout conversion from model-space, padding to kernel tile
-multiples, CPU fallback (interpret=True — this container has no TPU; the
-kernel body executes in the Pallas interpreter for correctness validation,
-see tests/test_kernels.py).
+multiples, and the execution mode, decided at call time from the default
+backend: compiled on a TPU, the Pallas interpreter on the CPU (where the
+tests validate the kernel bodies, see tests/test_kernels.py), and an error
+on any other platform.
 """
 from __future__ import annotations
 
@@ -17,7 +18,17 @@ from . import flash_attention as _fa
 from . import gbdt_predict as _gp
 from . import mamba_scan as _ms
 
-_INTERPRET = jax.default_backend() != "tpu"
+
+def _interpret() -> bool:
+    """True on the CPU backend, False on a TPU; any other platform raises
+    rather than running the kernels somewhere they were never validated."""
+    platform = jax.default_backend()
+    if platform == "tpu":
+        return False
+    if platform == "cpu":
+        return True
+    raise RuntimeError(f"Pallas kernels run compiled on a TPU or "
+                       f"interpreted on the CPU, not on {platform!r}")
 
 
 def _pad_to(x, axis: int, mult: int, value=0.0):
@@ -42,7 +53,7 @@ def flash_attention(q, k, v, causal: bool = True, window=None,
     kt = _pad_to(jnp.swapaxes(k, 1, 2), 2, bk)
     vt = _pad_to(jnp.swapaxes(v, 1, 2), 2, bk)
     out = _fa.flash_attention(qt, kt, vt, causal=causal, window=window,
-                              interpret=_INTERPRET, bq=bq, bk=bk)
+                              interpret=_interpret(), bq=bq, bk=bk)
     return jnp.swapaxes(out[:, :, :Sq], 1, 2)
 
 
@@ -60,37 +71,39 @@ def mamba_scan(u, dt, A, Bm, Cm, D, chunk: int = None, bd: int = None):
     dtp = _pad_to(dtp, 2, bd)
     Ap = _pad_to(A, 0, bd, value=-1.0)
     Dp = _pad_to(D, 0, bd)
-    y = _ms.mamba_scan(up, dtp, Ap, Bp, Cp, Dp, interpret=_INTERPRET,
+    y = _ms.mamba_scan(up, dtp, Ap, Bp, Cp, Dp, interpret=_interpret(),
                        chunk=chunk, bd=bd)
     y = y[:, :L, :Di]
     h_last = _ms.final_state(u, dt, A, Bm, Cm)
     return y, h_last
 
 
-def gbdt_predict(X, feats, thresholds, leaves, base: float = 0.0,
-                 bn: int = None, bt: int = None):
+def gbdt_leaf_indices(X, feats, thresholds, bn: int = None,
+                      bt: int = None):
     """numpy/jnp inputs in GBDTModel layout: X (n, F), feats (T, D) int,
-    thresholds (T, D), leaves (T, 2**D). Returns (n,) fp32."""
+    thresholds (T, D). Returns the (n, T) int32 leaf index of every row in
+    every tree."""
     X = jnp.asarray(X, jnp.float32)
     feats = jnp.asarray(feats, jnp.int32)
     thresholds = jnp.asarray(thresholds, jnp.float32)
-    leaves = jnp.asarray(leaves, jnp.float32)
     n, F = X.shape
     T, depth = feats.shape
     bn = bn or min(_gp.BN, max(n, 8))
     bt = bt or min(_gp.BT, max(T, 8))
     Xp = _pad_to(X, 0, bn)
-    featsp = _pad_to(feats, 0, bt)
-    # padded trees: +inf thresholds => all bits 0 => leaf 0; zero leaves
-    thrp = _pad_to(thresholds, 0, bt, value=np.float32(np.inf))
-    leavesp = _pad_to(leaves, 0, bt)
-    onehot = jax.nn.one_hot(featsp, F, dtype=jnp.float32)  # (T', D, F)
-    out = _gp.gbdt_predict(Xp, onehot, thrp, leavesp, jnp.float32(base),
-                           interpret=_INTERPRET, bn=bn, bt=bt)
-    return out[:n]
+    # trees on the lane axis: (D, T') thresholds and (D, F, T') one-hot
+    # feature selectors; padded trees are sliced off the result
+    thrp = _pad_to(thresholds, 0, bt).T
+    onehot = jnp.swapaxes(
+        jax.nn.one_hot(_pad_to(feats, 0, bt).T, F, dtype=jnp.float32), 1, 2)
+    idx = _gp.gbdt_leaf_indices(Xp, onehot, thrp, interpret=_interpret(),
+                                bn=bn, bt=bt)
+    return idx[:n, :T]
 
 
 def gbdt_predict_model(model, X):
-    """Convenience: run a fitted core.gbdt.GBDTModel through the kernel."""
-    return np.asarray(gbdt_predict(X, model.feats, model.thresholds,
-                                   model.leaves, model.base))
+    """Predict with a fitted core.gbdt.GBDTModel: leaf indices from the
+    kernel, leaf values summed on the host in float64 by the model itself
+    (bit-identical to ``model.predict`` whenever the indices agree)."""
+    idx = gbdt_leaf_indices(X, model.feats, model.thresholds)
+    return model.predict_from_leaves(np.asarray(idx))

@@ -61,17 +61,21 @@ def mamba_scan_ref(u, dt, A, Bm, Cm, D, h0=None):
     return y, h_last
 
 
+def gbdt_leaf_indices_ref(X, feats, thresholds):
+    """Oblivious-tree traversal: the (n, T) int32 leaf index of every row in
+    every tree. X: (n, F); feats: (T, D) int32; thresholds: (T, D)."""
+    bits = X[:, feats] > thresholds[None]                   # (n, T, D)
+    w = (1 << jnp.arange(feats.shape[1])).astype(jnp.int32)
+    return jnp.sum(bits.astype(jnp.int32) * w[None, None], axis=-1)
+
+
 def gbdt_predict_ref(X, feats, thresholds, leaves, base: float = 0.0):
     """Oblivious-tree ensemble inference.
 
     X: (n, F); feats: (T, D) int32; thresholds: (T, D); leaves: (T, 2**D).
     Returns (n,) fp32 predictions.
     """
-    gathered = X[:, feats]                                  # (n, T, D)
-    bits = gathered > thresholds[None]
-    D = feats.shape[1]
-    w = (1 << jnp.arange(D)).astype(jnp.int32)
-    idx = jnp.sum(bits.astype(jnp.int32) * w[None, None], axis=-1)  # (n, T)
+    idx = gbdt_leaf_indices_ref(X, feats, thresholds)       # (n, T)
     contrib = jnp.take_along_axis(
         jnp.broadcast_to(leaves[None], (X.shape[0],) + leaves.shape),
         idx[..., None], axis=2)[..., 0]

@@ -265,9 +265,8 @@ def _build_jitted(cfg, shape, mesh, microbatches):
 
 
 def _compile(cfg, shape, mesh, microbatches):
-    from .mesh import set_mesh
     jitted, args = _build_jitted(cfg, shape, mesh, microbatches)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = jitted.lower(*args)
         compiled = lowered.compile()
     return compiled
@@ -310,7 +309,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
         "fits_hbm": mem["total_bytes"] < 16e9,
         "memory_analysis": str(compiled.memory_analysis()),
         "cost_analysis_scanned": {
-            k: v for k, v in roofline.cost_analysis(compiled).items()
+            k: v for k, v in compiled.cost_analysis().items()
             if k in ("flops", "bytes accessed")},
     }
     if verbose:

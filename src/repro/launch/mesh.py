@@ -3,26 +3,7 @@ touches jax device state)."""
 from __future__ import annotations
 
 import jax
-
-
-def make_mesh(shape, axes, devices=None):
-    """Version-compat ``jax.make_mesh``: passes Auto axis_types where the
-    installed jax supports them (≥0.5), plain mesh otherwise."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        try:
-            return jax.make_mesh(shape, axes, devices=devices,
-                                 axis_types=(axis_type.Auto,) * len(axes))
-        except TypeError:
-            pass
-    return jax.make_mesh(shape, axes, devices=devices)
-
-
-def set_mesh(mesh):
-    """Version-compat mesh context: ``jax.set_mesh`` on new jax; on 0.4.x the
-    Mesh object is itself the context manager."""
-    sm = getattr(jax, "set_mesh", None)
-    return sm(mesh) if sm is not None else mesh
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -33,7 +14,8 @@ def make_production_mesh(*, multi_pod: bool = False):
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_mesh_for(n_devices: int, model_parallel: int = None):
@@ -41,5 +23,6 @@ def make_mesh_for(n_devices: int, model_parallel: int = None):
     model_parallel = model_parallel or min(n_devices, 16)
     while n_devices % model_parallel:
         model_parallel //= 2
-    return make_mesh((n_devices // model_parallel, model_parallel),
-                     ("data", "model"))
+    return jax.make_mesh((n_devices // model_parallel, model_parallel),
+                         ("data", "model"),
+                         axis_types=(AxisType.Auto, AxisType.Auto))

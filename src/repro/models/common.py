@@ -25,33 +25,10 @@ def dtype_of(name: str):
 
 
 def current_mesh():
-    """The active mesh, across jax versions: the abstract mesh on new jax,
-    falling through to the thread-local physical mesh (``with mesh:``
-    blocks) when the abstract one is absent or empty — some jax releases
-    have ``get_abstract_mesh`` but only physical-mesh contexts. Returns
-    None when no mesh (or an empty one) is active."""
-    get = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get is not None:
-        mesh = get()
-        if mesh is not None and getattr(mesh, "axis_names", ()):
-            return mesh
-    try:
-        from jax._src.mesh import thread_resources
-        mesh = thread_resources.env.physical_mesh
-    except Exception:
-        return None
-    return mesh if getattr(mesh, "axis_names", ()) else None
-
-
-def shard_map(f, mesh, in_specs, out_specs, check_vma: bool = False):
-    """Version-compat shard_map (``check_vma`` was ``check_rep`` pre-0.5)."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as sm_exp
-    return sm_exp(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=check_vma)
+    """The mesh set by ``jax.set_mesh``, or None when no mesh (or an empty
+    one) is active."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return mesh if mesh.axis_names else None
 
 
 def maybe_shard(x, spec: P):

@@ -179,7 +179,7 @@ def moe_sharded(p, x, cfg):
         in_specs += [P(None, TP), P(None, TP), P(TP, None)]
         args += [p["shared"]["w_gate"], p["shared"]["w_up"],
                  p["shared"]["w_down"]]
-    fn = common.shard_map(
+    fn = jax.shard_map(
         local, mesh=common.current_mesh(),
         in_specs=tuple(in_specs),
         out_specs=(bspec, P()),

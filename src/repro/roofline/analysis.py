@@ -152,17 +152,8 @@ def memory_stats(compiled) -> dict:
     return mem
 
 
-def cost_analysis(compiled) -> dict:
-    """Version-compat ``compiled.cost_analysis()`` (a one-element list of
-    dicts on jax 0.4.x, a plain dict on newer jax)."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
-
-
 def costs_of(compiled) -> dict:
-    cost = cost_analysis(compiled)
+    cost = compiled.cost_analysis() or {}   # None: backend reports none
     stats = parse_collectives(compiled.as_text())
     return {
         "flops": float(cost.get("flops", 0.0)),

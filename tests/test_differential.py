@@ -550,8 +550,17 @@ def _mixed_jobs(seed: int, pool_idx: int, quantum: float) -> list[Job]:
         stall_frac=float(rng.uniform(0.2, 0.5)),
         core_eff=float(rng.uniform(0.55, 0.85)))
         for i in range(3)]
-    jobs = list(stream_workload(APPS + novel, f["testbed"], n_jobs=30,
-                                seed=seed, n_devices=n_dev))
+    # every novel app must arrive, or the synthesizer has nothing to
+    # serve: redraw the stream (a fixed seed offset) until all three do
+    names = {a.name for a in novel}
+    attempt = 0
+    while True:
+        jobs = list(stream_workload(APPS + novel, f["testbed"], n_jobs=30,
+                                    seed=seed + 1000 * attempt,
+                                    n_devices=n_dev))
+        if names <= {j.app.name for j in jobs}:
+            break
+        attempt += 1
     return [dataclasses.replace(j, checkpoint_quantum=quantum)
             for j in jobs]
 

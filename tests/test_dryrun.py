@@ -22,10 +22,11 @@ SCRIPT = textwrap.dedent("""
     from repro.configs import get_config
     from repro.configs.base import SHAPES, reduce_for_smoke, ShapeSpec
     from repro.launch import dryrun as dr
-    from repro.launch.mesh import make_mesh, set_mesh
+    from jax.sharding import AxisType
     from repro.roofline import analysis as roofline
 
-    mesh = make_mesh((4, 2), ("data", "model"))
+    auto2 = (AxisType.Auto, AxisType.Auto)
+    mesh = jax.make_mesh((4, 2), ("data", "model"), axis_types=auto2)
 
     # small shapes so the compile stays quick
     shape_train = ShapeSpec("t", 128, 8, "train")
@@ -36,7 +37,7 @@ SCRIPT = textwrap.dedent("""
         cfg = dc.replace(cfg, param_dtype="bfloat16", remat="full")
         for shape in (shape_train, shape_decode):
             compiled = dr._compile(cfg, shape, mesh, 1)
-            cost = roofline.cost_analysis(compiled)
+            cost = compiled.cost_analysis()
             assert cost.get("flops", 0) > 0, (arch, shape.mode)
             mem = roofline.memory_stats(compiled)
             assert mem["total_bytes"] > 0
@@ -66,17 +67,17 @@ SCRIPT = textwrap.dedent("""
                           jnp.float32)
     out_ref, _ = moe(p, x, cfg)   # no mesh in scope -> jit oracle path
     # E-sharded: tp=2, E=4
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         out_e, _ = jax.jit(lambda p, x: moe_sharded(p, x, cfg))(p, x)
     assert float(jnp.max(jnp.abs(out_ref - out_e))) < 2e-4
     # F-sharded: tp=8 > E=4
-    mesh8 = make_mesh((1, 8), ("data", "model"))
-    with set_mesh(mesh8):
+    mesh8 = jax.make_mesh((1, 8), ("data", "model"), axis_types=auto2)
+    with jax.set_mesh(mesh8):
         out_f, _ = jax.jit(lambda p, x: moe_sharded(p, x, cfg))(p, x)
     assert float(jnp.max(jnp.abs(out_ref - out_f))) < 2e-4
     # batch=1 (long-context decode): dp must degrade gracefully
     x1 = x[:1]
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         out_1, _ = jax.jit(lambda p, x: moe_sharded(p, x, cfg))(p, x1)
     ref_1, _ = moe(p, x1, cfg)
     assert float(jnp.max(jnp.abs(ref_1 - out_1))) < 2e-4

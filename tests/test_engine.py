@@ -234,14 +234,35 @@ class TestPredictionService:
             np.testing.assert_array_equal(feats, app_feats[key[1]])
 
     def test_kernel_routing_matches_numpy(self, fitted, app_feats):
-        """Forced Pallas path (interpret on CPU) ≈ numpy reference."""
+        """Forced Pallas path (interpret on CPU) == numpy reference: the
+        kernel picks the leaves, the host sums them as numpy does."""
         svc_np = self._service(fitted, app_feats, use_kernel=False)
         svc_k = self._service(fitted, app_feats, use_kernel=True)
         name = APPS[0].name
         t_np, t_k = svc_np.table(name), svc_k.table(name)
         assert svc_k.stats.kernel_batches == 2   # power + time
-        np.testing.assert_allclose(t_k.P, t_np.P, rtol=2e-4)
-        np.testing.assert_allclose(t_k.T, t_np.T, rtol=2e-4)
+        np.testing.assert_array_equal(t_k.P, t_np.P)
+        np.testing.assert_array_equal(t_k.T, t_np.T)
+
+    def test_kernel_prefetch_matches_numpy_predictor_size(self, testbed,
+                                                          app_feats):
+        """The predictor's default ensembles (400 depth-4 trees each) on a
+        prefetch batch of every app's ladder: kernel tables equal the
+        numpy tables bit-for-bit."""
+        X, yp, yt, _ = build_dataset(APPS, testbed, seed=0)
+        full = EnergyTimePredictor(PredictorConfig()).fit(X, yp, yt)
+        assert full.power.gbdt.feats.shape == (400, 4)
+        svc_np = self._service(full, app_feats, use_kernel=False)
+        svc_k = self._service(full, app_feats, use_kernel=True)
+        names = [a.name for a in APPS]
+        svc_np.prefetch_tables(names)
+        svc_k.prefetch_tables(names)
+        assert svc_k.stats.kernel_batches == 2
+        for name in names:
+            np.testing.assert_array_equal(svc_k.table(name).P,
+                                          svc_np.table(name).P)
+            np.testing.assert_array_equal(svc_k.table(name).T,
+                                          svc_np.table(name).T)
 
     def test_unknown_app_error_carries_suggestion(self, fitted, app_feats):
         """PR 8 small fix: unknown apps raise a typed UnknownAppError

@@ -148,15 +148,50 @@ class TestMambaScan:
         assert mags[1] < mags[0] and mags[30] < 1e-3
 
 
+def _gbdt_model(feats, thr, leaves, base=0.0):
+    from repro.core.gbdt import GBDTModel, GBDTParams
+    return GBDTModel(base=base, feats=feats, thresholds=thr, leaves=leaves,
+                     split_gain=np.zeros(int(feats.max()) + 1),
+                     params=GBDTParams(iterations=feats.shape[0],
+                                       depth=feats.shape[1]))
+
+
 class TestGBDTPredict:
     def test_matches_model_predict_trained(self):
+        """Kernel leaf indices + the model's float64 leaf sum reproduce
+        model.predict bit-for-bit."""
         from repro.core.gbdt import GBDTParams, fit_gbdt
         rng = np.random.default_rng(0)
         X = rng.normal(size=(300, 10))
         y = np.sin(X[:, 0]) + X[:, 1] * X[:, 2]
         m = fit_gbdt(X, y, GBDTParams(iterations=120, depth=4))
-        got = ops.gbdt_predict_model(m, X)
-        np.testing.assert_allclose(got, m.predict(X), atol=1e-4, rtol=1e-4)
+        np.testing.assert_array_equal(ops.gbdt_predict_model(m, X),
+                                      m.predict(X))
+
+    def test_matches_model_predict_predictor_size(self):
+        """The predictor's own ensemble size — 400 depth-4 trees over the
+        23 DVFS features — spans several tree blocks of the kernel grid."""
+        from repro.core.gbdt import GBDTParams, fit_gbdt
+        rng = np.random.default_rng(1)
+        X = rng.normal(size=(600, 23))
+        y = np.sin(X[:, 0]) + X[:, 1] * X[:, 2] + 0.1 * X[:, 22]
+        m = fit_gbdt(X, y, GBDTParams(iterations=400, depth=4))
+        Xq = rng.normal(size=(520, 23))
+        np.testing.assert_array_equal(ops.gbdt_predict_model(m, Xq),
+                                      m.predict(Xq))
+
+    def test_leaf_sum_ignores_index_memory_order(self):
+        """Index arrays copied back from a device may be column-major; the
+        float64 leaf sum must not round differently for them."""
+        from repro.core.gbdt import GBDTParams, fit_gbdt
+        rng = np.random.default_rng(2)
+        X = rng.normal(size=(300, 23))
+        m = fit_gbdt(X, np.sin(X[:, 0]) + X[:, 1] * X[:, 2],
+                     GBDTParams(iterations=400, depth=4))
+        idx = m.leaf_indices(X)
+        np.testing.assert_array_equal(
+            m.predict_from_leaves(np.asfortranarray(idx.astype(np.int32))),
+            m.predict(X))
 
     @pytest.mark.parametrize("n,T,depth,F", [
         (17, 9, 2, 5),      # ragged everything (pad path)
@@ -169,11 +204,15 @@ class TestGBDTPredict:
         feats = rng.integers(0, F, size=(T, depth))
         thr = rng.normal(size=(T, depth))
         leaves = rng.normal(size=(T, 2 ** depth))
-        got = np.asarray(ops.gbdt_predict(X, feats, thr, leaves, base=1.5))
+        got = np.asarray(ops.gbdt_leaf_indices(X, feats, thr))
+        exp = np.asarray(ref.gbdt_leaf_indices_ref(
+            jnp.asarray(X), jnp.asarray(feats), jnp.asarray(thr)))
+        np.testing.assert_array_equal(got, exp)
+        pred = ops.gbdt_predict_model(_gbdt_model(feats, thr, leaves, 1.5), X)
         exp = np.asarray(ref.gbdt_predict_ref(
             jnp.asarray(X), jnp.asarray(feats), jnp.asarray(thr),
             jnp.asarray(leaves), base=1.5))
-        np.testing.assert_allclose(got, exp, atol=1e-4, rtol=1e-4)
+        np.testing.assert_allclose(pred, exp, atol=1e-4, rtol=1e-4)
 
     @settings(max_examples=10, deadline=None)
     @given(seed=st.integers(0, 10_000))
@@ -184,11 +223,15 @@ class TestGBDTPredict:
         feats = rng.integers(0, F, size=(T, depth))
         thr = rng.normal(size=(T, depth))
         leaves = rng.normal(size=(T, 2 ** depth))
-        got = np.asarray(ops.gbdt_predict(X, feats, thr, leaves))
+        got = np.asarray(ops.gbdt_leaf_indices(X, feats, thr))
+        exp = np.asarray(ref.gbdt_leaf_indices_ref(
+            jnp.asarray(X), jnp.asarray(feats), jnp.asarray(thr)))
+        np.testing.assert_array_equal(got, exp)
+        pred = ops.gbdt_predict_model(_gbdt_model(feats, thr, leaves), X)
         exp = np.asarray(ref.gbdt_predict_ref(
             jnp.asarray(X), jnp.asarray(feats), jnp.asarray(thr),
             jnp.asarray(leaves)))
-        np.testing.assert_allclose(got, exp, atol=1e-4, rtol=1e-4)
+        np.testing.assert_allclose(pred, exp, atol=1e-4, rtol=1e-4)
 
 
 class TestModelIntegration:

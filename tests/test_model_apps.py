@@ -99,6 +99,32 @@ class TestDerivedCounters:
             model_flops(cfg, phase_shape("decode"), n) * DECODE_STEPS,
             rel=1e-9)
 
+    def test_aot_counters_from_compiled_decode_step(self):
+        """A compiled decode step's cost analysis yields per-chip
+        (flops, bytes), and derive_counters takes them over the analytic
+        terms when given the artifact."""
+        import jax
+        import jax.numpy as jnp
+        from repro.configs.base import reduce_for_smoke
+        from repro.core.model_apps import aot_counters
+        from repro.models import model
+        from repro.train.serve import make_serve_step
+        cfg = reduce_for_smoke(get_config("smollm_360m"))
+        params = jax.eval_shape(lambda: model.init(cfg,
+                                                   jax.random.PRNGKey(0)))
+        cache = jax.eval_shape(lambda: model.init_cache(cfg, 2, 16))
+        tok = jax.ShapeDtypeStruct((2, 1), jnp.int32)
+        pos = jax.ShapeDtypeStruct((), jnp.int32)
+        compiled = jax.jit(make_serve_step(cfg)).lower(
+            params, cache, tok, pos).compile()
+        got = aot_counters(compiled, n_chips=2)
+        assert got is not None
+        flops, nbytes = got
+        assert flops > 0 and nbytes > 0
+        refined = derive_counters(cfg, "decode", n_chips=2,
+                                  compiled=compiled)
+        assert (refined["flops"], refined["hbm_bytes"]) == (flops, nbytes)
+
     def test_train_apps_carry_collectives(self):
         """Train steps are collective-heavy: every train app ships
         gradient all-reduce bytes over >= 2 chips; serving phases ship

@@ -276,17 +276,18 @@ MULTIDEV_SCRIPT = textwrap.dedent("""
     sys.path.insert(0, "src")
     from repro.ckpt import checkpoint as ckpt
     from repro.dist.collectives import compressed_psum, init_error
-    from repro.launch.mesh import make_mesh
-    from repro.models.common import shard_map
+    from jax.sharding import AxisType
+
+    auto2 = (AxisType.Auto, AxisType.Auto)
 
     # --- elastic checkpoint reshard: save on 8-dev mesh, restore on 4 ----
-    mesh8 = make_mesh((4, 2), ("data", "model"))
+    mesh8 = jax.make_mesh((4, 2), ("data", "model"), axis_types=auto2)
     w = jnp.arange(64.0).reshape(8, 8)
     w8 = jax.device_put(w, NamedSharding(mesh8, P("data", "model")))
     d = tempfile.mkdtemp()
     ckpt.save(d, 0, {"w": w8})
-    mesh4 = make_mesh((2, 2), ("data", "model"),
-                      devices=jax.devices()[:4])
+    mesh4 = jax.make_mesh((2, 2), ("data", "model"), axis_types=auto2,
+                          devices=jax.devices()[:4])
     restored, _ = ckpt.restore(d, {"w": w}, mesh=mesh4,
                                specs={"w": P("data", "model")})
     np.testing.assert_array_equal(np.asarray(restored["w"]), np.asarray(w))
@@ -294,11 +295,11 @@ MULTIDEV_SCRIPT = textwrap.dedent("""
     print("ELASTIC_OK")
 
     # --- compressed gradient psum over a pod axis with error feedback ----
-    mesh = make_mesh((2, 4), ("pod", "data"))
+    mesh = jax.make_mesh((2, 4), ("pod", "data"), axis_types=auto2)
     g = jax.random.normal(jax.random.PRNGKey(0), (2, 256))
 
-    @partial(shard_map, mesh=mesh, in_specs=(P("pod"), P("pod")),
-             out_specs=(P("pod"), P("pod")))
+    @partial(jax.shard_map, mesh=mesh, in_specs=(P("pod"), P("pod")),
+             out_specs=(P("pod"), P("pod")), check_vma=False)
     def reduce_fn(g_local, err):
         out, new_err = compressed_psum({"g": g_local}, "pod",
                                        {"g": err})
