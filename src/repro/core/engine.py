@@ -101,6 +101,7 @@ from typing import Callable, Iterable, Iterator, Optional, Sequence
 
 import numpy as np
 
+from . import tracing
 from .batch_decide import DecisionCore
 from .dvfs import ClockPair, DeviceClass
 from .policies import (BudgetManager, DeviceCandidate, Policy,
@@ -781,9 +782,16 @@ class EventEngine:
         # job can *finish being simulated* long before its end time)
         fb_pending: list[tuple[float, int, ExecutionRecord]] = []
         fb_seq = 0
+        # the recorder's state at the start of the run holds for the run;
+        # a job's wait is timed from its first enqueue
+        tr = tracing.ON
+        waves = 0
+        enqueued_ns: dict[int, int] = {}
 
         def enqueue(j: Job, upto: float) -> None:
             nonlocal counter
+            if tr:
+                enqueued_ns.setdefault(j.job_id, tracing.clock())
             heapq.heappush(queue, (edf_key(j), counter, j))
             counter += 1
             if self._prefetch:
@@ -814,10 +822,19 @@ class EventEngine:
                     if stream.exhausted:
                         break
                     free_t = max(free_t, stream.peek_arrival())
+            wave = None
+            if (tr and not stream.exhausted
+                    and stream.peek_arrival() <= free_t):
+                waves += 1
+                wave = tracing.begin("engine.wave", key=waves)
             while not stream.exhausted and stream.peek_arrival() <= free_t:
+                if tr:
+                    span = tracing.begin("engine.arrival")
                 job = stream.pop()
                 if note_cold is not None:
                     note_cold(job.app)    # register unseen apps (PR 8)
+                if tr:
+                    tracing.end(span)
                 if adm is not None and not adm.check(job, free_t, queue):
                     continue              # shed or parked — never queued
                 enqueue(job, free_t)
@@ -832,6 +849,8 @@ class EventEngine:
                 self.service.prefetch_tables(self._admitted,
                                              self._prefetch_classes)
                 self._admitted.clear()
+            if wave is not None:
+                tracing.end(wave)
             if not queue:
                 heapq.heappush(free, (free_t, dev))
                 continue
@@ -842,6 +861,9 @@ class EventEngine:
                 # capture manager state before on_pop/apply mutate it
                 bm_snaps = [bm.snapshot() for bm in self.budget_managers]
             dl_key, cnt_key, job = heapq.heappop(queue)  # EDF (paper line 5)
+            if tr:
+                decide = tracing.begin("engine.decide", key=job.job_id,
+                                       parent="engine.job")
             for bm in self.budget_managers:
                 bm.on_pop(job)
             start = max(free_t, job.arrival)
@@ -879,7 +901,12 @@ class EventEngine:
                                 bm.restore(snap)
                         heapq.heappush(queue, (dl_key, cnt_key, job))
                         heapq.heappush(free, (wait_t, dev))
+                        if tr:
+                            tracing.end(decide)
                         continue
+            if tr:
+                tracing.record("engine.job", enqueued_ns.pop(job.job_id),
+                               tracing.end(decide), key=job.job_id)
             if self.hooks.on_dispatch:
                 self.hooks.on_dispatch(job, dev, clock, start)
             self.device_clocks[dev] = clock

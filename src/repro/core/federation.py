@@ -74,6 +74,7 @@ from typing import Callable, Iterable, Optional, Sequence
 
 import numpy as np
 
+from . import tracing
 from .dvfs import ClockPair, DeviceClass, DVFSConfig
 from .powercap import GRANT_POLICIES, PowerCapCoordinator
 from .preemption import PreemptionConfig, PreemptionManager
@@ -363,19 +364,31 @@ class FacilityCoordinator:
                                 else list(device_classes[lo:lo + size])))
 
     def advance(self, t: float) -> None:
+        tr = tracing.ON
+        if tr:
+            outer = tracing.begin("coord.advance")
+            span = tracing.begin("coord.rack_advance")
         for rack in self.racks:
             rack.coord.advance(t)
-        if self.n_racks > 1:
-            if self._grant_tiers:
-                live = set()
-                for rack in self.racks:
-                    live.update(rack.offset + d
-                                for d in rack.coord.active_grants())
-                self._grant_tiers = {d: w for d, w in
-                                     self._grant_tiers.items() if d in live}
-            if (self.share_policy != "static"
-                    and math.isfinite(self.cap_w)):
-                self._rebalance()
+        multi = self.n_racks > 1
+        if multi and self._grant_tiers:
+            live = set()
+            for rack in self.racks:
+                live.update(rack.offset + d
+                            for d in rack.coord.active_grants())
+            self._grant_tiers = {d: w for d, w in
+                                 self._grant_tiers.items() if d in live}
+        if tr:
+            tracing.end(span)
+        if (multi and self.share_policy != "static"
+                and math.isfinite(self.cap_w)):
+            if tr:
+                span = tracing.begin("coord.rebalance")
+            self._rebalance()
+            if tr:
+                tracing.end(span)
+        if tr:
+            tracing.end(outer)
 
     def _rebalance(self) -> None:
         """Re-split unallocated facility headroom across racks by the
@@ -431,6 +444,9 @@ class FacilityCoordinator:
         if (got >= needed_w - 1e-9 or self.n_racks == 1
                 or not self.escalation or not math.isfinite(self.cap_w)):
             return got
+        tr = tracing.ON
+        if tr:
+            span = tracing.begin("coord.facility_escalate")
         self.stats.escalations += 1
         deficit = needed_w - got
         pool = self.cap_w - math.fsum(r.coord.cap_w for r in self.racks)
@@ -454,6 +470,8 @@ class FacilityCoordinator:
         got = rack.coord.escalate(local, needed_w, start)
         if got >= needed_w - 1e-9:
             self.stats.rescues += 1
+        if tr:
+            tracing.end(span)
         return got
 
     def commit(self, dev: int, request_w: float, end: float,

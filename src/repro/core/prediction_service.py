@@ -79,6 +79,7 @@ from typing import Optional, Sequence
 import jax
 import numpy as np
 
+from . import tracing
 from .correlate import CorrelationIndex
 from .dvfs import ClockPair, DVFSConfig, DeviceClass
 from .features import clock_features
@@ -232,6 +233,8 @@ class ServiceStats:
     stacked_hits: int = 0         # joint decisions served from stacked cache
     prefetched_tables: int = 0    # tables built via batched prefetch
     synthesized_builds: int = 0   # cold-start analytic ladder builds
+    kernel_cells: int = 0         # (row, tree) cells sent to the kernel
+    kernel_padded_cells: int = 0  # (row, tree) cells it computed, padded
 
     def summary(self) -> str:
         return (f"table_builds={self.table_builds} hits={self.table_hits} "
@@ -281,6 +284,7 @@ class PredictionService:
         #: listed fall back to the shared ``app_features`` (+ correlation).
         self.class_features = class_features or {}
         self.stats = ServiceStats()
+        self._waves = 0               # prefetch waves the recorder saw
 
         self.clocks: tuple[ClockPair, ...] = tuple(dvfs.clock_list())
         self._clock_X = [clock_features(c, dvfs) for c in self.clocks]
@@ -638,8 +642,13 @@ class PredictionService:
         bit-for-bit (pinned in tests/test_batch_decide.py).
 
         Returns the number of tables built (correlated apps sharing a
-        resolved profile count once, exactly like :meth:`base_table`)."""
+        resolved profile count once, exactly like :meth:`base_table`).
+
+        With the recorder on, a call that builds a table is one
+        ``predict.wave`` span, from its first build to its return."""
         built = 0
+        tr = tracing.ON
+        wave = None
         for cls in device_classes:
             ck = self.register_class(cls)
             if ck is None:
@@ -657,6 +666,8 @@ class PredictionService:
                     # synthesized ladders are analytic, not predictor
                     # rows — build individually, keep them out of the
                     # stacked predictor batch
+                    if tr and wave is None:
+                        wave = self._begin_wave()
                     self.base_table(name, cls)
                     built += 1
                     continue
@@ -664,11 +675,19 @@ class PredictionService:
                 todo.append((key, feats))
             if not todo:
                 continue
+            if tr:
+                if wave is None:
+                    wave = self._begin_wave()
+                span = tracing.begin("predict.stack")
             L = len(clocks)
             X = np.stack([np.concatenate([feats, cx])
                           for _, feats in todo for cx in clock_X])
+            if tr:
+                tracing.end(span)
             P = self._predict(self.predictor.power, X)
             T = self._predict(self.predictor.time, X)
+            if tr:
+                span = tracing.begin("predict.store")
             for i, (key, _) in enumerate(todo):
                 tab = ClockTable(clocks=clocks,
                                  P=P[i * L:(i + 1) * L].copy(),
@@ -678,7 +697,15 @@ class PredictionService:
                 self.stats.table_builds += 1
                 self.stats.prefetched_tables += 1
                 built += 1
+            if tr:
+                tracing.end(span)
+        if wave is not None:
+            tracing.end(wave)
         return built
+
+    def _begin_wave(self) -> tuple:
+        self._waves += 1
+        return tracing.begin("predict.wave", key=self._waves, annotate=True)
 
     def _predict(self, target, X: np.ndarray) -> np.ndarray:
         """One regressor over a batch; routes big GBDT batches to Pallas."""
@@ -693,15 +720,43 @@ class PredictionService:
         if use:
             self.stats.kernel_batches += 1
             return self._kernel_predict(target, X)
-        return target.predict(X)
+        if not tracing.ON:
+            return target.predict(X)
+        span = tracing.begin("predict.numpy")
+        out = target.predict(X)
+        tracing.end(span)
+        return out
 
-    @staticmethod
-    def _kernel_predict(target, X: np.ndarray) -> np.ndarray:
+    def _kernel_predict(self, target, X: np.ndarray) -> np.ndarray:
+        """Leaf indices from the kernel, summed on the host in float64 by
+        the model (:func:`repro.kernels.ops.gbdt_predict_model`'s steps,
+        taken one by one so that the recorder can time each)."""
         from ..kernels import ops  # lazy: keeps core importable without jax
+        g = target.gbdt
+        n, n_trees = X.shape[0], g.feats.shape[0]
+        n_pad, t_pad = ops.gbdt_padded_shape(n, n_trees)
+        self.stats.kernel_cells += n * n_trees
+        self.stats.kernel_padded_cells += n_pad * t_pad
+        tr = tracing.ON
+        if tr:
+            span = tracing.begin("predict.stack")
         Xe = target.enc.transform(X) if target.enc is not None else X
-        raw = np.asarray(ops.gbdt_predict_model(target.gbdt, Xe),
-                         dtype=np.float64)
-        return target._decode_target(X, raw)
+        if tr:
+            tracing.end(span)
+            span = tracing.begin("predict.launch", annotate=True)
+        idx = ops.gbdt_leaf_indices(Xe, g.feats, g.thresholds)
+        if tr:
+            tracing.end(span)
+            span = tracing.begin("predict.device_wait", annotate=True)
+        idx = np.asarray(idx)
+        if tr:
+            tracing.end(span)
+            span = tracing.begin("predict.leaf_sum")
+        raw = np.asarray(g.predict_from_leaves(idx), dtype=np.float64)
+        out = target._decode_target(X, raw)
+        if tr:
+            tracing.end(span)
+        return out
 
     # ------------------------------------------------------------------ #
     #  Point predictions (budget-manager inputs)

@@ -71,4 +71,5 @@ def gbdt_leaf_indices(X, feats_onehot, thresholds, interpret: bool = False,
         out_specs=pl.BlockSpec((bn, bt), lambda ni, ti: (ni, ti)),
         out_shape=jax.ShapeDtypeStruct((n, T), jnp.int32),
         interpret=interpret,
+        name="gbdt_leaf_indices",
     )(X, feats_onehot, thresholds)

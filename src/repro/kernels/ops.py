@@ -31,8 +31,13 @@ def _interpret() -> bool:
                        f"interpreted on the CPU, not on {platform!r}")
 
 
+def _padded(size: int, mult: int) -> int:
+    """``size`` rounded up to a multiple of ``mult``."""
+    return size + (-size) % mult
+
+
 def _pad_to(x, axis: int, mult: int, value=0.0):
-    pad = (-x.shape[axis]) % mult
+    pad = _padded(x.shape[axis], mult) - x.shape[axis]
     if pad == 0:
         return x
     widths = [(0, 0)] * x.ndim
@@ -78,6 +83,21 @@ def mamba_scan(u, dt, A, Bm, Cm, D, chunk: int = None, bd: int = None):
     return y, h_last
 
 
+def _gbdt_blocks(n: int, n_trees: int, bn: int = None,
+                 bt: int = None) -> tuple[int, int]:
+    """(row block, tree block) of a kernel call over ``n`` rows and
+    ``n_trees`` trees."""
+    return bn or min(_gp.BN, max(n, 8)), bt or min(_gp.BT, max(n_trees, 8))
+
+
+def gbdt_padded_shape(n: int, n_trees: int) -> tuple[int, int]:
+    """(rows, trees) the kernel computes for ``n`` rows and ``n_trees``
+    trees at :func:`gbdt_leaf_indices`' default blocks: each axis padded
+    up to a multiple of its block."""
+    bn, bt = _gbdt_blocks(n, n_trees)
+    return _padded(n, bn), _padded(n_trees, bt)
+
+
 def gbdt_leaf_indices(X, feats, thresholds, bn: int = None,
                       bt: int = None):
     """numpy/jnp inputs in GBDTModel layout: X (n, F), feats (T, D) int,
@@ -88,8 +108,7 @@ def gbdt_leaf_indices(X, feats, thresholds, bn: int = None,
     thresholds = jnp.asarray(thresholds, jnp.float32)
     n, F = X.shape
     T, depth = feats.shape
-    bn = bn or min(_gp.BN, max(n, 8))
-    bt = bt or min(_gp.BT, max(T, 8))
+    bn, bt = _gbdt_blocks(n, T, bn, bt)
     Xp = _pad_to(X, 0, bn)
     # trees on the lane axis: (D, T') thresholds and (D, F, T') one-hot
     # feature selectors; padded trees are sliced off the result

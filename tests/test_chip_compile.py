@@ -7,6 +7,8 @@ The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and tests that exist on one
 xdist worker must exist on every worker.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -54,6 +56,17 @@ def test_gbdt_leaf_indices_predictor_size(one_chip):
                           [((4096, 23), f32), ((4, 23, 512), f32),
                            ((4, 512), f32)])
     assert "tpu_custom_call" in text
+
+
+def test_gbdt_leaf_indices_custom_call_name(one_chip):
+    """The kernel's custom call keeps the name the benchmark's trace
+    reduction matches, ``gbdt_leaf_indices``."""
+    f32 = jnp.float32
+    text = _compiled_text(one_chip, gp.gbdt_leaf_indices,
+                          [((1024, 23), f32), ((4, 23, 512), f32),
+                           ((4, 512), f32)])
+    calls = re.findall(r"%([\w.-]+) = \S+ custom-call\(", text)
+    assert any(c.startswith("gbdt_leaf_indices") for c in calls), calls
 
 
 def test_flash_attention_smollm_heads(one_chip):
