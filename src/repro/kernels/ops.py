@@ -5,6 +5,18 @@ multiples, and the execution mode, decided at call time from the default
 backend: compiled on a TPU, the Pallas interpreter on the CPU (where the
 tests validate the kernel bodies, see tests/test_kernels.py), and an error
 on any other platform.
+
+GBDT ensembles keep their kernel operands on the device. The first
+:func:`gbdt_leaf_indices` call for an ensemble builds its (D, T') float32
+thresholds and (D, F, T') one-hot feature selectors, trees padded to the
+tree block, and an LRU of ``GBDT_CONSTANTS_MAX`` entries keeps them. The
+key is the content of ``feats`` and ``thresholds`` (their bytes after the
+cast to int32 and float32) with the feature count and tree block, so an
+ensemble refitted in place, or another with the same shapes, never gets
+stale operands. :func:`gbdt_constants_info` counts hits and builds
+(``misses``). Rows are cast to float32 and zero-padded to the row block on
+the host, so a call is one transfer in, the kernel, and one slice: the
+device sees only padded row counts.
 """
 from __future__ import annotations
 
@@ -98,26 +110,54 @@ def gbdt_padded_shape(n: int, n_trees: int) -> tuple[int, int]:
     return _padded(n, bn), _padded(n_trees, bt)
 
 
+GBDT_CONSTANTS_MAX = 16   # ensembles whose operands stay on the device
+
+
+@functools.lru_cache(maxsize=GBDT_CONSTANTS_MAX)
+def _gbdt_constants(feats: bytes, thresholds: bytes, n_trees: int,
+                    depth: int, n_feat: int, bt: int):
+    """Device operands of one ensemble, keyed by its int32 ``feats`` and
+    float32 ``thresholds`` bytes: (D, T') thresholds and (D, F, T') one-hot
+    feature selectors, trees on the lane axis. Padded trees split on
+    feature 0 at 0 and are sliced off the result."""
+    f = np.zeros((_padded(n_trees, bt), depth), np.int32)
+    thr = np.zeros(f.shape, np.float32)
+    f[:n_trees] = np.frombuffer(feats, np.int32).reshape(n_trees, depth)
+    thr[:n_trees] = np.frombuffer(thresholds, np.float32).reshape(
+        n_trees, depth)
+    onehot = f.T[:, None, :] == np.arange(n_feat)[None, :, None]
+    return (jnp.asarray(onehot.astype(np.float32)),
+            jnp.asarray(np.ascontiguousarray(thr.T)))
+
+
+gbdt_constants_info = _gbdt_constants.cache_info
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2))
+def _leading(idx, n: int, n_trees: int):
+    return idx[:n, :n_trees]
+
+
 def gbdt_leaf_indices(X, feats, thresholds, bn: int = None,
                       bt: int = None):
     """numpy/jnp inputs in GBDTModel layout: X (n, F), feats (T, D) int,
     thresholds (T, D). Returns the (n, T) int32 leaf index of every row in
-    every tree."""
-    X = jnp.asarray(X, jnp.float32)
-    feats = jnp.asarray(feats, jnp.int32)
-    thresholds = jnp.asarray(thresholds, jnp.float32)
-    n, F = X.shape
-    T, depth = feats.shape
-    bn, bt = _gbdt_blocks(n, T, bn, bt)
-    Xp = _pad_to(X, 0, bn)
-    # trees on the lane axis: (D, T') thresholds and (D, F, T') one-hot
-    # feature selectors; padded trees are sliced off the result
-    thrp = _pad_to(thresholds, 0, bt).T
-    onehot = jnp.swapaxes(
-        jax.nn.one_hot(_pad_to(feats, 0, bt).T, F, dtype=jnp.float32), 1, 2)
+    every tree, a device array."""
+    X = np.asarray(X)
+    feats = np.asarray(feats, np.int32)
+    thresholds = np.asarray(thresholds, np.float32)
+    n, n_feat = X.shape
+    n_trees, depth = feats.shape
+    bn, bt = _gbdt_blocks(n, n_trees, bn, bt)
+    onehot, thrp = _gbdt_constants(feats.tobytes(), thresholds.tobytes(),
+                                   n_trees, depth, n_feat, bt)
+    Xp = np.zeros((_padded(n, bn), n_feat), np.float32)
+    Xp[:n] = X
+    # the kernel is looked up on the module at each call: a test or a
+    # control run may swap it
     idx = _gp.gbdt_leaf_indices(Xp, onehot, thrp, interpret=_interpret(),
                                 bn=bn, bt=bt)
-    return idx[:n, :T]
+    return _leading(idx, n, n_trees)
 
 
 def gbdt_predict_model(model, X):

@@ -234,6 +234,93 @@ class TestGBDTPredict:
         np.testing.assert_allclose(pred, exp, atol=1e-4, rtol=1e-4)
 
 
+def _ensemble(seed, n_trees=400, depth=4, n_feat=23):
+    """A random ensemble of the predictor's size whose thresholds are
+    float32 values, so the numpy path's float64 compares agree with the
+    kernel's float32 ones."""
+    rng = np.random.default_rng(seed)
+    feats = rng.integers(0, n_feat, size=(n_trees, depth)).astype(np.int32)
+    thr = rng.normal(size=(n_trees, depth)).astype(np.float32)
+    leaves = rng.normal(size=(n_trees, 2 ** depth))
+    return _gbdt_model(feats, thr.astype(np.float64), leaves)
+
+
+def _rows(seed, n, n_feat=23):
+    rng = np.random.default_rng(seed)
+    return rng.normal(size=(n, n_feat)).astype(np.float32).astype(np.float64)
+
+
+class TestGBDTLaunch:
+    """The launch path of ``ops.gbdt_leaf_indices``: rows padded on the
+    host, each ensemble's operands built once and kept on the device."""
+
+    @pytest.mark.parametrize("n", [1, 255, 257, 1088])
+    def test_row_counts_match_reference(self, n):
+        m = _ensemble(7)
+        X = _rows(n, n)
+        got = ops.gbdt_leaf_indices(X, m.feats, m.thresholds)
+        assert isinstance(got, jax.Array)
+        assert got.shape == (n, 400) and got.dtype == jnp.int32
+        exp = ref.gbdt_leaf_indices_ref(
+            jnp.asarray(X), jnp.asarray(m.feats), jnp.asarray(m.thresholds))
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(exp))
+        np.testing.assert_array_equal(np.asarray(got), m.leaf_indices(X))
+
+    def test_constants_built_once_per_ensemble(self):
+        m = _ensemble(11)
+        before = ops.gbdt_constants_info()
+        for n in (64, 300, 64):
+            X = _rows(n, n)
+            np.testing.assert_array_equal(
+                np.asarray(ops.gbdt_leaf_indices(X, m.feats, m.thresholds)),
+                m.leaf_indices(X))
+        after = ops.gbdt_constants_info()
+        assert after.misses - before.misses == 1
+        assert after.hits - before.hits == 2
+        assert after.currsize <= ops.GBDT_CONSTANTS_MAX
+
+    def test_same_shapes_other_splits_get_their_own_operands(self):
+        a, b = _ensemble(21), _ensemble(22)
+        X = _rows(0, 300)
+        before = ops.gbdt_constants_info()
+        got_a = np.asarray(ops.gbdt_leaf_indices(X, a.feats, a.thresholds))
+        got_b = np.asarray(ops.gbdt_leaf_indices(X, b.feats, b.thresholds))
+        np.testing.assert_array_equal(got_a, a.leaf_indices(X))
+        np.testing.assert_array_equal(got_b, b.leaf_indices(X))
+        assert (got_a != got_b).any()
+        # an ensemble changed in place is another key
+        a.thresholds[:] = b.thresholds
+        a.feats[:] = b.feats
+        np.testing.assert_array_equal(
+            np.asarray(ops.gbdt_leaf_indices(X, a.feats, a.thresholds)),
+            got_b)
+        after = ops.gbdt_constants_info()
+        assert after.misses - before.misses == 2
+        assert after.hits - before.hits == 1
+
+    def test_kernel_swapped_after_a_call_is_used(self, monkeypatch):
+        """A control run swaps ``gbdt_predict.gbdt_leaf_indices`` for
+        another kernel: the next call runs the new one."""
+        from repro.kernels import gbdt_predict
+        m = _ensemble(31)
+        X = _rows(1, 40)
+        first = np.asarray(ops.gbdt_leaf_indices(X, m.feats, m.thresholds))
+        np.testing.assert_array_equal(first, m.leaf_indices(X))
+        orig = gbdt_predict.gbdt_leaf_indices
+
+        def swapped(Xp, onehot, thrp, **kw):
+            return orig(Xp, onehot, thrp, **kw) ^ 1
+
+        monkeypatch.setattr(gbdt_predict, "gbdt_leaf_indices", swapped)
+        np.testing.assert_array_equal(
+            np.asarray(ops.gbdt_leaf_indices(X, m.feats, m.thresholds)),
+            first ^ 1)
+        monkeypatch.undo()
+        np.testing.assert_array_equal(
+            np.asarray(ops.gbdt_leaf_indices(X, m.feats, m.thresholds)),
+            first)
+
+
 class TestModelIntegration:
     def test_attention_flash_impl_matches_xla(self):
         """attn_impl='flash' through the real attention module."""
