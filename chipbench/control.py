@@ -2,7 +2,8 @@
 it must catch.
 
     python3 chipbench/control.py --workload <cell> --seconds <s> \\
-        --mode <program|control|half|altered|stale> --seeds <n> [<n> ...]
+        --mode <program|control|half|altered|stale|sheddable> \\
+        --seeds <n> [<n> ...]
 
 runs the cell once per seed in one process and prints each run's compared
 numbers; the benchmark's own runs never run this. The modes:
@@ -21,6 +22,8 @@ numbers; the benchmark's own runs never run this. The modes:
   produces it.
 * ``stale``: the facility coordinator's ``commit`` returns with its state
   unchanged.
+* ``sheddable``: admission control sees every job's tier as sheddable
+  (cells whose configuration has ``admission``).
 
 The one-chip cells have no exchange between chips to leave out.
 """
@@ -82,8 +85,10 @@ def high_leaf_indices():
 
 def install(mode: str):
     """Break the timed path for ``mode``; returns the ``fault(service,
-    coordinator)`` hook for :func:`chipbench.harness.run_cell` (or None)
-    and an undo function."""
+    coordinator, admission)`` hook for :func:`chipbench.harness.run_cell`
+    (or None) and an undo function."""
+    import dataclasses
+
     import numpy as np
 
     from repro.kernels import gbdt_predict, ops
@@ -108,8 +113,22 @@ def install(mode: str):
         ops.gbdt_leaf_indices = broken
         return None, lambda: setattr(ops, "gbdt_leaf_indices", orig)
     if mode == "stale":
-        def fault(_service, coord):
+        def fault(_service, coord, _admission):
             coord.commit = lambda *a, **kw: None
+        return fault, lambda: None
+    if mode == "sheddable":
+        def fault(_service, _coord, adm):
+            if adm is None:
+                raise ValueError("the sheddable fault needs a configuration "
+                                 "with admission")
+            check = adm.check
+
+            def every_tier_sheddable(job, now, queue):
+                tier = dataclasses.replace(job.tier, sheddable=True)
+                return check(dataclasses.replace(job, tier=tier), now,
+                             queue)
+
+            adm.check = every_tier_sheddable
         return fault, lambda: None
     raise ValueError(f"unknown mode {mode!r}")
 
@@ -122,7 +141,7 @@ def main(argv=None) -> int:
     p.add_argument("--seconds", type=float, required=True)
     p.add_argument("--mode", default="program",
                    choices=("program", "control", "half", "altered",
-                            "stale"))
+                            "stale", "sheddable"))
     p.add_argument("--seeds", type=int, nargs="+", required=True)
     a = p.parse_args(argv)
 

@@ -11,6 +11,14 @@ plus a uniform slack, a checkpoint quantum per job. With no new apps it
 equals that function job for job at the same seed. A new app is a variant
 of the suite app its job drew, decided and drawn from a second random
 stream, so the arrivals and the base apps do not depend on the share.
+
+A mix may also list SLA tiers (``tiers``). Each job's tier is drawn from a
+third random stream, and a tiered job's deadline is anchored at its
+arrival, ``arrival + (1 + U[tier.slack_range]) * t_a``, the rule of
+``repro.core.workload.multi_tenant_workload``: under overload a virtual
+default-clock anchor drifts away with the backlog and makes every deadline
+loose. Arrivals, apps, job ids and quanta stay those of the untiered
+stream, and without ``tiers`` every job carries the inert default tier.
 """
 from __future__ import annotations
 
@@ -19,7 +27,8 @@ from typing import Callable, Iterator, Optional, Sequence
 
 import numpy as np
 
-from repro.core import AppProfile, DeviceClass, Job, Testbed
+from repro.core import (DEFAULT_TIER, AppProfile, DeviceClass, Job,
+                        Testbed, TierSpec)
 
 #: AppProfile seeds of novel apps start here, clear of the suite's seeds.
 NOVEL_SEED_BASE = 1_000_000
@@ -39,9 +48,23 @@ def novel_app(base: AppProfile, index: int, rng: np.random.Generator,
         wiggle_power=float(latents["wiggle_power"]))
 
 
+def tiers_of(traffic: dict) -> tuple[list[TierSpec], np.ndarray]:
+    """The mix's tiers, built from its data alone, and their cumulative
+    shares, normalised to end at 1 (empty without ``tiers``)."""
+    specs, shares = [], []
+    for t in traffic.get("tiers", ()):
+        specs.append(TierSpec(
+            str(t["name"]), priority=int(t["priority"]),
+            weight=float(t["weight"]), sheddable=bool(t["sheddable"]),
+            slack_range=tuple(float(x) for x in t["slack_range"])))
+        shares.append(float(t["share"]))
+    cum = np.cumsum(shares)
+    return specs, cum / cum[-1] if specs else cum
+
+
 def stream(suite: Sequence[AppProfile], testbed: Testbed,
            pool: Sequence[DeviceClass], traffic: dict, seed: int,
-           novel_seed: int = 0,
+           novel_seed: int = 0, tier_seed: int = 0,
            on_novel: Optional[Callable[[AppProfile], None]] = None,
            stop: Optional[Callable[[], bool]] = None,
            n_jobs: Optional[int] = None) -> Iterator[Job]:
@@ -50,12 +73,16 @@ def stream(suite: Sequence[AppProfile], testbed: Testbed,
     ``traffic`` keys: ``burst_frac`` (jobs per burst as a share of the
     pool), ``utilization``, ``slack_range`` and ``quantum_frac`` as in
     ``multi_rack_workload``; ``novel_share`` (the chance that a job is the
-    first submission of a new app) and ``novel_latents``. ``on_novel`` is
+    first submission of a new app) and ``novel_latents``; ``tiers``, each
+    with ``name``, ``priority``, ``weight``, ``sheddable``, ``slack_range``
+    and ``share``, drawn from ``tier_seed``. ``on_novel`` is
     called with each new app before its job is yielded (the harness
     profiles and registers it there). The stream ends when ``stop()`` turns
     true, checked before each job, or after ``n_jobs`` jobs."""
     rng = np.random.default_rng(seed)
     nrng = np.random.default_rng(novel_seed)
+    trng = np.random.default_rng(tier_seed)
+    tiers, cum = tiers_of(traffic)
     share = float(traffic.get("novel_share", 0.0))
     latents = traffic.get("novel_latents")
     burst = max(1, int(len(pool) * float(traffic["burst_frac"])))
@@ -92,7 +119,13 @@ def stream(suite: Sequence[AppProfile], testbed: Testbed,
                 t_a = float(t_dc_dev[dev][idx])
             done = max(float(dev_free[dev]), now) + t_a
             dev_free[dev] = done
-            slack = float(rng.uniform(*slack_range)) * t_a
-            yield Job(app=app, arrival=now, deadline=done + slack,
-                      job_id=jid, checkpoint_quantum=quantum_frac * t_a)
+            deadline = done + float(rng.uniform(*slack_range)) * t_a
+            tier = DEFAULT_TIER
+            if tiers:
+                k = int(np.searchsorted(cum, trng.random()))
+                tier = tiers[min(k, len(tiers) - 1)]
+                deadline = now + (1.0 + float(
+                    trng.uniform(*tier.slack_range))) * t_a
+            yield Job(app=app, arrival=now, deadline=deadline, job_id=jid,
+                      checkpoint_quantum=quantum_frac * t_a, tier=tier)
             jid += 1

@@ -21,7 +21,7 @@ from typing import Callable, Optional
 
 import numpy as np
 
-from . import fixtures, gen, reference
+from . import fixtures, gen, program_trace, reference
 from . import spans as spans_mod
 from . import trace as trace_mod
 
@@ -85,8 +85,8 @@ class Run:
 
     setup_s: float
     window_s: float
-    placed: int                         # jobs dispatched or shed
-    latencies_s: np.ndarray             # admission -> dispatch, per job
+    placed: int                         # jobs dispatched (a shed one is not)
+    latencies_s: np.ndarray             # wait -> dispatch, per dispatched job
     compiles: int                       # programs lowered in the window
     spans: Optional[spans_mod.Spans] = None
     #: (rows, features, trees, depth) of each kernel call in the window
@@ -95,6 +95,15 @@ class Run:
     busy_s: float = 0.0                 # device busy in the traced window
     traced_window_s: float = 0.0
     peaks: Optional[dict] = None        # peaks.json entry of this chip
+    shed: int = 0                       # jobs admission control shed
+    #: job id -> host clock at which its wait began: its first admission
+    #: check, or its enqueue where no admission control runs
+    started: dict = dataclasses.field(default_factory=dict)
+    #: the window's own program objects, for readers of their counters:
+    #: ``service``, ``coordinator``, ``admission`` (or None), ``result``
+    parts: dict = dataclasses.field(default_factory=dict)
+    #: the program's recorder spans (traced runs)
+    program_spans: Optional[program_trace.ProgramSpans] = None
 
 
 def require_chips(n: int) -> list:
@@ -117,6 +126,15 @@ def coordinator(config: dict):
                                share_policy=c["share_policy"],
                                grant_policy=c["grant_policy"],
                                guard=float(c["guard"]))
+
+
+def admission(config: dict):
+    """A fresh admission controller with the configuration's ``admission``
+    keyword arguments; None where the configuration has none."""
+    from repro.core import AdmissionController
+
+    kw = config.get("admission")
+    return None if kw is None else AdmissionController(**kw)
 
 
 def testbed(config: dict, seed: int):
@@ -199,8 +217,23 @@ def _kernel_calls(calls: list):
         ops.gbdt_leaf_indices = orig
 
 
-def _reduce_trace(run: Run, logdir: str, t_window: float) -> dict:
-    """Busy time, kernel time and the breakdown from the profiler trace."""
+def _wait_from_check(check: Callable, started: dict,
+                     now: Callable) -> Callable:
+    """Admission control's ``check`` that starts each job's wait at its
+    first check, so that a job parked and released later waits from its
+    arrival at the controller, not from its release."""
+    def timed(job, t, queue):
+        started.setdefault(job.job_id, now())
+        return check(job, t, queue)
+    return timed
+
+
+def _reduce_trace(run: Run, logdir: str, t_window: float,
+                  records: list) -> dict:
+    """Busy time, kernel time and the breakdown from the profiler trace;
+    the recorder's ``records`` go to ``run.program_spans`` and name the
+    idle gaps once more (:func:`program_trace.extend`); ``span_cost`` says
+    what both span systems took from the window."""
     planes = trace_mod.read_xplane(logdir)
     tr_lo, tr_hi = trace_mod.find_event(planes, "chipbench_traced")
     w_lo, w_hi = trace_mod.find_event(planes, "chipbench_window")
@@ -216,7 +249,10 @@ def _reduce_trace(run: Run, logdir: str, t_window: float) -> dict:
         lo, hi = g_lo * 1e-9 - offset, g_hi * 1e-9 - offset
         gaps.append([_host_layer(run.spans, lo, hi, t_window),
                      (g_hi - g_lo) * 1e-9])
-    return {"device_ops": [[n, s] for n, s in top], "idle_gaps": gaps}
+    out = {"device_ops": [[n, s] for n, s in top], "idle_gaps": gaps}
+    program_trace.extend(out, run, planes, records, t_window)
+    out["span_cost"] = program_trace.span_cost(run)
+    return out
 
 
 def _host_layer(sp: spans_mod.Spans, lo: float, hi: float,
@@ -241,18 +277,20 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
 
     ``t_start`` is the host clock at process start. ``check_chips=False``
     and ``fault`` serve the benchmark's own tests: ``fault(service,
-    coordinator)`` is called before the window to break the timed path."""
+    coordinator, admission)`` is called before the window to break the
+    timed path. A traced run has the program's span recorder on from the
+    suite prefetch to the end of the window."""
     import jax
 
     from repro.core import (V5E_DVFS, EngineHooks, PredictionService,
-                            profile_features, run_schedule)
+                            profile_features, run_schedule, tracing)
 
     devs = require_chips(cell["chips"]) if check_chips else jax.devices()
     config, traffic = cell["config"], cell["traffic"]
     # the stream is the mix's own, the same for every run seed; the run
     # seed draws the predictor, the profiles and the measurement noise
     s_stream = int(traffic["stream_seed"])
-    s_novel = fixtures.sub_seed(s_stream, 2)
+    s_novel, s_tier = (fixtures.sub_seed(s_stream, k) for k in (2, 5))
     s_sched, s_prof = (fixtures.sub_seed(seed, k) for k in (3, 4))
     now = time.perf_counter
 
@@ -265,6 +303,9 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
                             app_features=dict(features), testbed=tb)
     warm_kernel_shapes(predictor,
                        wave_rows(pool, traffic, svc.kernel_min_rows))
+    if trace:
+        tracing.take()
+        tracing.enable()
 
     sp = spans_mod.Spans() if trace else None
     logdir = tempfile.mkdtemp(prefix="chipbench-") if trace else None
@@ -275,17 +316,19 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
     for cls in classes:                 # every suite table, one wave each
         svc.prefetch_tables([a.name for a in f["suite"]], (cls,))
 
-    coord, pol = coordinator(config), policy(config)
+    coord, pol, adm = coordinator(config), policy(config), admission(config)
     if fault is not None:
-        fault(svc, coord)
-    admitted: dict[int, float] = {}
+        fault(svc, coord, adm)
+    started: dict[int, float] = {}
     latencies: list[float] = []
+    if adm is not None:
+        adm.check = _wait_from_check(adm.check, started, now)
 
     def on_admit(job, _t):
-        admitted[job.job_id] = now()
+        started.setdefault(job.job_id, now())
 
     def on_dispatch(job, _dev, _clock, _t):
-        latencies.append(now() - admitted[job.job_id])
+        latencies.append(now() - started[job.job_id])
 
     def on_novel(app):
         # one default-clock profiling run, registered in the service: the
@@ -298,7 +341,8 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
     deadline = [math.inf]
     jobs: list = []
     src = gen.stream(f["suite"], tb, pool, traffic, seed=s_stream,
-                     novel_seed=s_novel, on_novel=on_novel,
+                     novel_seed=s_novel, tier_seed=s_tier,
+                     on_novel=on_novel,
                      stop=lambda: now() >= deadline[0])
     if trace:
         src = sp.iterate(src, "gen")
@@ -334,12 +378,15 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
         result = run_schedule(
             recorded(src), pol, testbed(config, s_sched), seed=s_sched,
             service=svc, device_classes=pool, power_coordinator=coord,
-            hooks=EngineHooks(on_admit=on_admit, on_dispatch=on_dispatch))
+            hooks=EngineHooks(on_admit=on_admit, on_dispatch=on_dispatch),
+            admission=adm)
         t1 = now()
         lowered[1] = False
     jax.monitoring.unregister_event_duration_listener(on_event)
     traced.__exit__(None, None, None)
     if trace:
+        records = tracing.take()
+        tracing.disable()
         jax.profiler.stop_trace()
 
     stats = [d.memory_stats() or {} for d in devs[:cell["chips"]]]
@@ -348,9 +395,11 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
               "memory_peak_bytes": max(int(s.get("peak_bytes_in_use", 0))
                                        for s in stats)}
     run = Run(setup_s=t0 - t_start, window_s=t1 - t0,
-              placed=len(result.records) + result.shed_count,
+              placed=len(result.records), shed=result.shed_count,
               latencies_s=np.asarray(latencies), compiles=lowered[0],
-              spans=sp)
+              spans=sp, started=started,
+              parts={"service": svc, "coordinator": coord, "admission": adm,
+                     "result": result})
     breakdown = None
     if trace:
         peaks = json.loads((HERE / "peaks.json").read_text())["devices"]
@@ -359,13 +408,13 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
                            f"{devs[0].device_kind!r} in peaks.json")
         run.peaks = peaks[devs[0].device_kind]
         run.kernel_calls = calls
-        breakdown = _reduce_trace(run, logdir, t0)
+        breakdown = _reduce_trace(run, logdir, t0, records)
         shutil.rmtree(logdir, ignore_errors=True)
         device["busy_s"] = run.busy_s
         device["window_s"] = run.traced_window_s
 
-    checks = compare(config, svc, f, features, pool, classes, jobs, result,
-                     s_sched)
+    checks = compare(config, traffic, svc, f, features, pool, classes, jobs,
+                     result, s_sched)
     metrics = {}
     for m in cell["per_layer" if trace else "end_to_end"]:
         value = reader(cell["metrics_dir"], m["name"])(run)
@@ -380,8 +429,8 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
     return out
 
 
-def compare(config: dict, svc, f: dict, features: dict, pool, classes,
-            jobs: list, result, s_sched: int) -> dict:
+def compare(config: dict, traffic: dict, svc, f: dict, features: dict,
+            pool, classes, jobs: list, result, s_sched: int) -> dict:
     """The numbers that decide ``correct``, each with its limit."""
     from repro.core import V5E_DVFS, PredictionService, run_schedule
 
@@ -390,8 +439,11 @@ def compare(config: dict, svc, f: dict, features: dict, pool, classes,
         svc, f["predictor"], features, list(features), classes)
     checks = {"tables_differ": {"value": differ, "limit": 0},
               "tables_missing": {"value": missing, "limit": 0}}
+    sheddable = {t["name"] for t in traffic.get("tiers", ())
+                 if t["sheddable"]}
     held = reference.guarantees(result, jobs, pool, float(config["cap_w"]),
-                                s_sched, float(config["measurement_noise"]))
+                                s_sched, float(config["measurement_noise"]),
+                                sheddable)
     for name, value in held.items():
         checks[name] = {"value": value,
                         "limit": reference.CAP_SLACK_W
@@ -402,7 +454,7 @@ def compare(config: dict, svc, f: dict, features: dict, pool, classes,
     want = run_schedule(jobs, policy(config), testbed(config, s_sched),
                         seed=s_sched, service=ref, device_classes=pool,
                         power_coordinator=coordinator(config),
-                        batch_decide=False)
+                        batch_decide=False, admission=admission(config))
     for name, gap in reference.schedule_gaps(result, want).items():
         checks[name] = {"value": gap, "limit": 0}
     checks["tables_compared"] = {"value": compared, "floor": 1}

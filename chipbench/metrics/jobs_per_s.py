@@ -1,5 +1,5 @@
-"""Jobs placed (dispatched or shed) per second of the whole window, the
-drain after the last arrival included."""
+"""Jobs dispatched per second of the whole window, the drain after the last
+arrival included; a job that admission control shed is not counted."""
 
 
 def read(run):

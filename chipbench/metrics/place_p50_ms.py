@@ -1,5 +1,7 @@
-"""Median wall time from a job's admission to its dispatch, over every job
-dispatched in the window; its wave's table builds are inside it."""
+"""Median wall time from the start of a job's wait to its dispatch, over
+every job dispatched in the window; its wave's table builds are inside it.
+The wait starts at the job's enqueue, or at its first admission check
+where admission control runs, so a parked job's time parked counts."""
 import numpy as np
 
 

@@ -1,5 +1,6 @@
-"""99th percentile of the wall time from admission to dispatch, over every
-job dispatched in the window: what a stall of the control plane costs the
+"""99th percentile of the wall time from the start of a job's wait to its
+dispatch, over every job dispatched in the window (the wait as
+``place_p50_ms`` times it): what a stall of the control plane costs the
 jobs queued behind it."""
 import numpy as np
 

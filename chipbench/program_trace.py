@@ -1,30 +1,28 @@
-"""A traced run of one cell with the program's own span recorder on.
+"""The program's own spans in a traced run, and the metrics read from them.
 
-    python3 chipbench/program_trace.py --workload <cell> --seed <n> \\
-        --seconds <s>
-
-From the root of a checkout. The run is ``run.py --trace 1``'s, with the
-recorder of ``repro.core.tracing`` on from set-up's suite prefetch to the
-end of the window. Its result line adds the in-program metrics of
-:data:`METRICS` (readers in ``chipbench/metrics/``) and two keys to
+In a traced run (``run.py --trace 1``) the harness turns the recorder of
+``repro.core.tracing`` on from set-up's suite prefetch to the end of the
+window and hands its records to :func:`extend`, which attaches them to the
+run as ``program_spans`` for the readers of the ``program_span`` metrics
+of ``BENCHMARK.json`` (in ``chipbench/metrics/``) and adds two keys to
 ``breakdown``: ``idle_spans``, the longest idle gaps of the device each
 named by the deepest in-program span that covers most of it, and
 ``clock_skew_us``, how far the recorder's clock, carried onto the trace's
 by the window's anchor, lands from the profiler's own annotations of the
-same wave spans. The benchmark's own runs do not turn the recorder on; the
-harness would take this in by enabling the recorder before the suite
-prefetch in ``run_cell`` and calling :func:`extend` from
-``_reduce_trace``.
+same wave spans. :func:`span_cost` adds a third, ``span_cost``: what the
+recorder and the benchmark's own wrappers (``chipbench/spans.py``) took
+from the window.
+
+    python3 chipbench/program_trace.py --workload <cell> --seed <n> \\
+        --seconds <s>
+
+is ``run.py`` with ``--trace 1``.
 """
 from __future__ import annotations
 
-import contextlib
-import json
 import pathlib
 import sys
 import time
-
-T_START = time.perf_counter()
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
 if __name__ == "__main__":
@@ -36,32 +34,6 @@ from chipbench import trace as trace_mod  # noqa: E402
 #: Span names the program also enters as profiler annotations
 #: (``<name>:<key>``).
 ANNOTATED = ("predict.wave", "predict.launch", "predict.device_wait")
-_WAVE = ["fed64-novel"]
-_ALL = ["fed64-novel", "fed64-recur", "pod256-recur"]
-#: The in-program metrics, as ``BENCHMARK.json`` would list them.
-METRICS = [
-    {"name": "wave_stack_share", "unit": "%", "better": "lower",
-     "source": "program_span", "layer": "prediction service",
-     "moves": "jobs_per_s", "workloads": _WAVE},
-    {"name": "kernel_launch_share", "unit": "%", "better": "lower",
-     "source": "program_span", "layer": "kernel", "moves": "jobs_per_s",
-     "workloads": _WAVE},
-    {"name": "kernel_wait_share", "unit": "%", "better": "lower",
-     "source": "program_span", "layer": "kernel", "moves": "jobs_per_s",
-     "workloads": _WAVE},
-    {"name": "leaf_sum_share", "unit": "%", "better": "lower",
-     "source": "program_span", "layer": "prediction service",
-     "moves": "jobs_per_s", "workloads": _WAVE},
-    {"name": "place_wait_predict_share", "unit": "%", "better": "lower",
-     "source": "program_span", "layer": "engine", "moves": "place_p99_ms",
-     "workloads": _WAVE},
-    {"name": "rack_advance_share", "unit": "%", "better": "lower",
-     "source": "program_span", "layer": "facility coordinator",
-     "moves": "jobs_per_s", "workloads": _ALL},
-    {"name": "rebalance_share", "unit": "%", "better": "lower",
-     "source": "program_span", "layer": "facility coordinator",
-     "moves": "jobs_per_s", "workloads": _ALL},
-]
 
 
 class ProgramSpans(spans_mod.Spans):
@@ -162,75 +134,50 @@ def extend(breakdown: dict, run, planes: list[dict], records,
     breakdown["clock_skew_us"] = clock_skew_us(planes, sp, offset)
 
 
-@contextlib.contextmanager
-def recorder_on(harness):
-    """``harness.run_cell`` with the recorder on from the suite prefetch
-    (right after the kernel shapes are warmed) until the trace is
-    reduced, whose result :func:`extend` completes."""
+def span_cost(run, n: int = 20_000) -> dict:
+    """What the two span systems of a traced run took from its window, as
+    an estimate: the spans each recorded inside the window times the host
+    time of one, timed here after the window on ``n`` empty spans (a
+    recorder span nested in another, as most are; an outermost call
+    through a benchmark wrapper). Both systems are on in every traced
+    run, so the per-layer shares hold their cost; this says how much."""
     from repro.core import tracing
 
-    warm, reduce = harness.warm_kernel_shapes, harness._reduce_trace
-
-    def warm_then_record(*args):
-        warm(*args)
-        tracing.take()
-        tracing.enable()
-
-    def reduce_and_extend(run, logdir, t_window):
-        records = tracing.take()
-        tracing.disable()
-        out = reduce(run, logdir, t_window)
-        extend(out, run, trace_mod.read_xplane(logdir), records, t_window)
-        return out
-
-    harness.warm_kernel_shapes = warm_then_record
-    harness._reduce_trace = reduce_and_extend
+    lo, hi = run.program_spans.window
+    n_rec = sum(lo <= s and e <= hi
+                for ivs in run.program_spans.by_layer.values()
+                for s, e in ivs)
+    n_wrap = sum(lo <= s and e <= hi
+                 for layer, ivs in run.spans.by_layer.items()
+                 if layer != "engine" for s, e in ivs)
+    tracing.enable()
     try:
-        yield
+        outer = tracing.begin("calibrate")
+        t0 = time.perf_counter()
+        for _ in range(n):
+            tracing.end(tracing.begin("calibrate.inner"))
+        rec_s = (time.perf_counter() - t0) / n
+        tracing.end(outer)
     finally:
-        harness.warm_kernel_shapes, harness._reduce_trace = warm, reduce
-        tracing.disable()
         tracing.take()
-
-
-def run_cell(cell: dict, seed: int, seconds: float, t_start: float,
-             check_chips: bool = True) -> dict:
-    """One traced run of ``cell`` with the recorder on; the result object
-    of ``run.py --trace 1`` with the in-program metrics and keys."""
-    from chipbench import harness
-
-    cell = dict(cell, per_layer=cell["per_layer"] + [
-        m for m in METRICS if cell["name"] in m["workloads"]])
-    with recorder_on(harness):
-        return harness.run_cell(cell, seed, seconds, True, t_start,
-                                check_chips=check_chips)
+        tracing.disable()
+    call = spans_mod.Spans().wrap(lambda: None, "calibrate", "calibrate")
+    t0 = time.perf_counter()
+    for _ in range(n):
+        call()
+    wrap_s = (time.perf_counter() - t0) / n
+    return {"recorder_spans": n_rec, "recorder_span_us": rec_s * 1e6,
+            "recorder_share": 100.0 * n_rec * rec_s / run.window_s,
+            "wrapper_calls": n_wrap, "wrapper_call_us": wrap_s * 1e6,
+            "wrapper_share": 100.0 * n_wrap * wrap_s / run.window_s}
 
 
 def main(argv=None) -> int:
-    import argparse
+    """``run.py --trace 1`` with the same arguments."""
+    from chipbench import run
 
-    p = argparse.ArgumentParser(description=__doc__.splitlines()[0])
-    p.add_argument("--workload", required=True)
-    p.add_argument("--seed", type=int, required=True)
-    p.add_argument("--seconds", type=float, required=True)
-    a = p.parse_args(argv)
-
-    import jax
-
-    from chipbench import harness
-    from repro.launch.compile_cache import use_compile_cache
-
-    use_compile_cache()
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-    cell = harness.load_cell(a.workload)
-    try:
-        harness.require_chips(cell["chips"])
-    except harness.NoChip as e:
-        print(f"chipbench: {e}", file=sys.stderr)
-        return 3
-    out = run_cell(cell, a.seed, a.seconds, T_START)
-    print(json.dumps(out), flush=True)
-    return 0
+    args = sys.argv[1:] if argv is None else list(argv)
+    return run.main(args + ["--trace", "1"])
 
 
 if __name__ == "__main__":

@@ -11,7 +11,8 @@ Three things are compared, after the window has closed:
   they are; no table, leaf index or prediction of the program is used.
 * The schedule's guarantees, worked out here from the records, the jobs
   the window handed over and the benchmark's frozen copy of the fleet's
-  physics (``chipbench/physics.py``): every job placed exactly once, no
+  physics (``chipbench/physics.py``): every job placed exactly once (a
+  shed job too), no job shed whose tier the mix does not mark sheddable, no
   device running two jobs at once or a job before it arrived, each
   record's time, draw and energy those of its app at its clock with the
   run's measurement noise replayed, misses counted from the deadlines,
@@ -103,13 +104,16 @@ def schedule_gaps(got, want) -> dict[str, float]:
 
 
 def guarantees(result, jobs, pool, cap_w: float, seed: int,
-               noise: float) -> dict[str, float]:
+               noise: float, sheddable=frozenset()) -> dict[str, float]:
     """The schedule's guarantees, each as a count or an excess that is 0
     when it holds. ``jobs`` are the jobs handed to the engine, ``pool`` the
     positional device classes, ``seed`` and ``noise`` the engine's
     measurement noise (one time draw, then one draw of power, per dispatch
-    in dispatch order)."""
+    in dispatch order), ``sheddable`` the names of the tiers the traffic
+    lets admission control shed."""
     by_id = {j.job_id: j for j in jobs}
+    shed_unsheddable = sum(by_id[j.job_id].tier.name not in sheddable
+                           for j in result.shed if j.job_id in by_id)
     placed = collections.Counter(r.job_id for r in result.records)
     placed.update(j.job_id for j in result.shed)
     misplaced = sum(placed.get(i, 0) != 1 for i in by_id)
@@ -170,4 +174,5 @@ def guarantees(result, jobs, pool, cap_w: float, seed: int,
     return {"jobs_misplaced": misplaced, "overlaps": overlaps,
             "physics_differ": physics_differ,
             "misses_recount": abs(result.misses - int(recount)),
-            "grants_missing": grants_missing, "cap_excess_w": excess}
+            "grants_missing": grants_missing, "cap_excess_w": excess,
+            "shed_unsheddable": shed_unsheddable}

@@ -38,7 +38,8 @@ def test_a_program_schedule_holds_every_guarantee(schedule):
     jobs, result = schedule
     assert held(jobs, result) == {
         "jobs_misplaced": 0, "overlaps": 0, "physics_differ": 0,
-        "misses_recount": 0, "grants_missing": 0, "cap_excess_w": 0.0}
+        "misses_recount": 0, "grants_missing": 0, "cap_excess_w": 0.0,
+        "shed_unsheddable": 0}
 
 
 def broken(result, i, **change):
@@ -72,3 +73,14 @@ def test_a_missed_deadline_must_be_counted(schedule):
                    for r in result.records]
     assert result.misses > 0
     assert held(jobs, out)["misses_recount"] == result.misses
+
+
+def test_a_shed_job_must_be_of_a_sheddable_tier(schedule):
+    jobs, result = schedule
+    out = copy.copy(result)
+    out.records = [r for r in result.records if r.job_id != jobs[0].job_id]
+    out.shed = [jobs[0]]
+    assert held(jobs, out)["jobs_misplaced"] == 0
+    assert held(jobs, out)["shed_unsheddable"] == 1
+    assert reference.guarantees(out, jobs, POOL, CAP, SEED, 0.01,
+                                {jobs[0].tier.name})["shed_unsheddable"] == 0

@@ -1,14 +1,22 @@
 """The in-program metrics and trace keys of ``chipbench/program_trace.py``
-on constructed runs and on the constructed trace of ``test_trace``."""
-import types
+on constructed runs, on the constructed trace of ``test_trace``, and in a
+traced run of the harness on the CPU."""
+import json
 
 import numpy as np
 import pytest
 
 from chipbench import harness, program_trace
+from chipbench.tests.test_runs import root  # noqa: F401 (fixture)
 from chipbench.tests.test_trace import constructed
 
 MS = 1_000_000
+BENCH = json.loads((harness.ROOT / "BENCHMARK.json").read_text())
+#: the metrics ``BENCHMARK.json`` reads from the program's recorder
+RECORDER = ("wave_stack_share", "kernel_launch_share", "kernel_wait_share",
+            "leaf_sum_share", "place_wait_predict_share",
+            "rack_advance_share", "rebalance_share")
+PROGRAM_METRICS = [m for m in BENCH["per_layer"] if m["name"] in RECORDER]
 #: host clock of the window's start; the trace's window starts at 30 ms
 T_WINDOW = 1000.0
 
@@ -73,7 +81,7 @@ def test_window_shares(name, span):
         100 * (span[1] - span[0]) / 0.070)
 
 
-@pytest.mark.parametrize("name", [m["name"] for m in program_trace.METRICS])
+@pytest.mark.parametrize("name", [m["name"] for m in PROGRAM_METRICS])
 def test_readers_read_nothing_without_the_recorder(name):
     run = harness.Run(setup_s=1.0, window_s=1.0, placed=1,
                       latencies_s=np.zeros(0), compiles=0)
@@ -117,28 +125,107 @@ def test_clock_skew_without_annotations_is_none():
     assert program_trace.clock_skew_us(constructed(), sp, 0.0) is None
 
 
-def test_recorder_on_wraps_and_restores(monkeypatch):
+@pytest.fixture(scope="module")
+def traced(tmp_path_factory, root):
+    """One traced ``run_cell`` of ``tiny-novel`` on the CPU, reporting the
+    per-layer metrics of fed64-novel: ``(result, run)``. The profiler's CPU
+    trace gains a device plane with one operation, and ``peaks.json`` an
+    entry for the CPU, so that the trace's reduction runs."""
+    import json
+    import time
+
+    import repro.core.prediction_service as ps
+    from chipbench.tests.test_runs import SEED, keep_runs
+
+    here = tmp_path_factory.mktemp("peaks")
+    peaks = json.loads((harness.HERE / "peaks.json").read_text())
+    peaks["devices"]["cpu"] = peaks["devices"]["TPU v5 lite"]
+    (here / "peaks.json").write_text(json.dumps(peaks))
+    read_xplane = harness.trace_mod.read_xplane
+
+    def with_device(logdir):
+        planes = read_xplane(logdir)
+        lo, _ = harness.trace_mod.find_event(planes, "chipbench_traced")
+        planes.append({"name": "/device:TPU:0", "lines": {
+            harness.trace_mod.OPS_LINE: [("fusion.1", lo + 1000, 1000)]}})
+        return planes
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(ps, "_on_tpu", lambda: True)
+        mp.setenv("REPRO_GBDT_KERNEL_MIN_ROWS", "48")
+        mp.setattr(harness, "HERE", here)
+        mp.setattr(harness.trace_mod, "read_xplane", with_device)
+        runs = keep_runs(mp)
+        bench = json.loads((harness.ROOT / "BENCHMARK.json").read_text())
+        cell = harness.load_cell("tiny-novel", root=root)
+        cell["per_layer"] = [m for m in bench["per_layer"]
+                             if "fed64-novel" in m["workloads"]]
+        out = harness.run_cell(cell, SEED, 1.0, True, time.perf_counter(),
+                               check_chips=False)
+    return out, runs[0]
+
+
+def test_traced_run_records_from_the_prefetch_to_the_window_end(traced):
     from repro.core import tracing
 
-    calls = []
-    fake = types.SimpleNamespace(
-        warm_kernel_shapes=lambda *a: calls.append("warm"),
-        _reduce_trace=lambda run, logdir, t: {
-            "idle_gaps": [["engine", 0.045]]})
-    warm, reduce = fake.warm_kernel_shapes, fake._reduce_trace
-    monkeypatch.setattr(program_trace.trace_mod, "read_xplane",
-                        lambda logdir: annotated_trace())
+    out, run = traced
+    assert out["correct"], out["checks"]
+    assert not tracing.ON
+    sp = run.program_spans
+    t0, t1 = sp.window
+    waves = sp.by_layer["predict.wave"]
+    # the suite prefetch of set-up, then waves of new apps in the window
+    assert waves[0][1] <= t0 and sp.in_window("predict.wave")
+    assert max(e for ivs in sp.by_layer.values() for _, e in ivs) <= t1
+    assert set(out["breakdown"]) >= {"device_ops", "idle_gaps",
+                                     "idle_spans", "clock_skew_us"}
+
+
+def test_traced_run_reports_its_cells_program_metrics(traced):
+    out, _ = traced
+    mine = {m["name"] for m in PROGRAM_METRICS
+            if "fed64-novel" in m["workloads"]}
+    assert len(mine) == 7
+    assert all(out["metrics"][name]["value"] > 0 for name in mine), (
+        out["metrics"])
+
+
+def test_traced_run_reports_what_its_spans_cost(traced):
+    out, run = traced
+    cost = out["breakdown"]["span_cost"]
+    assert cost["recorder_spans"] > 0 and cost["wrapper_calls"] > 0
+    assert 0 < cost["recorder_span_us"] < 1e3
+    assert 0 < cost["wrapper_call_us"] < 1e3
+    assert cost["recorder_share"] == pytest.approx(
+        cost["recorder_spans"] * cost["recorder_span_us"] * 1e-4
+        / run.window_s)
+
+
+def test_span_cost_counts_the_spans_inside_the_window():
+    from chipbench.spans import Spans
+    from repro.core import tracing
+
     run = run_of()
-    with program_trace.recorder_on(fake):
-        fake.warm_kernel_shapes()
-        assert tracing.ON
-        tracing.end(tracing.begin("coord.advance"))
-        out = fake._reduce_trace(run, "logdir", T_WINDOW)
-        assert not tracing.ON
-    assert calls == ["warm"]
-    assert (fake.warm_kernel_shapes, fake._reduce_trace) == (warm, reduce)
-    assert list(run.program_spans.by_layer) == ["coord.advance"]
-    assert set(out) == {"idle_gaps", "idle_spans", "clock_skew_us"}
+    run.spans = Spans()
+    t0 = T_WINDOW
+    run.spans.by_layer = {
+        "engine": [(t0, t0 + 0.070)],
+        "coord": [(t0 + 0.001, t0 + 0.002), (t0 - 0.010, t0 - 0.009)],
+        "gen": [(t0 + 0.003, t0 + 0.004), (t0 + 0.060, t0 + 0.080)]}
+    cost = program_trace.span_cost(run, n=100)
+    # the job, the window's wave, its launch and the rebalance lie in the
+    # window, set-up's wave does not; one wrapped call of each layer ends
+    # inside it
+    assert cost["recorder_spans"] == 4
+    assert cost["wrapper_calls"] == 2
+    assert not tracing.ON and tracing.take() == []
+
+
+@pytest.mark.parametrize("kind", ["end_to_end", "per_layer"])
+def test_every_benchmark_metric_has_a_reader(kind):
+    for m in BENCH[kind]:
+        assert callable(harness.reader(harness.HERE / "metrics",
+                                       m["name"])), m["name"]
 
 
 def test_kernel_useful_share_equals_the_service_counters(monkeypatch):

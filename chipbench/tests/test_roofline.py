@@ -48,6 +48,7 @@ def test_roofline_share_and_silence():
 def test_end_to_end_readers():
     run = run_with()
     assert read("jobs_per_s", run) == 250.0
+    assert read("jobs_per_s", run_with(shed=300)) == 250.0
     assert read("setup_s", run) == 12.0
     lat = run.latencies_s * 1e3
     assert read("place_p50_ms", run) == pytest.approx(np.percentile(lat, 50))
