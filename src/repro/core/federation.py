@@ -69,6 +69,7 @@ benchmarks/bench_federation.py):
 from __future__ import annotations
 
 import dataclasses
+import heapq
 import math
 from typing import Callable, Iterable, Optional, Sequence
 
@@ -182,12 +183,16 @@ class FacilityStats:
     transfers: int = 0         # sibling cap transfers executed
     transferred_w: float = 0.0  # total watts moved between rack caps
     rebalances: int = 0        # share-policy cap re-splits
+    advances: int = 0          # calls to ``advance``
+    rack_refreshes: int = 0    # racks whose grants or allocations changed
+    #                            since the previous ``advance`` (summed)
 
     def summary(self) -> str:
         return (f"escalations={self.escalations} rescues={self.rescues} "
                 f"transfers={self.transfers} "
                 f"transferred={self.transferred_w:.0f}W "
-                f"rebalances={self.rebalances}")
+                f"rebalances={self.rebalances} advances={self.advances} "
+                f"rack_refreshes={self.rack_refreshes}")
 
 
 class RackCoordinator:
@@ -293,6 +298,17 @@ class FacilityCoordinator:
         ]
         self.stats = FacilityStats()
         self._grant_tiers: dict[int, float] = {}
+        #: (end, rack) for every lease end a rack sets; ``advance`` pops the
+        #: due ones and visits only those racks
+        self._due: list[tuple[float, int]] = []
+        #: racks whose grants or allocations changed since the last
+        #: ``advance``; only their cached re-split inputs are recomputed
+        self._changed: set[int] = set()
+        #: per rack: allocated watts and share weight, the re-split's inputs
+        self._floors: list[float] = [0.0] * self.n_racks
+        self._weights: list[float] = [1.0] * self.n_racks
+        for rack in self.racks:
+            rack.coord.watch(self._due, self._changed, rack.index)
 
     # -- topology routing ---------------------------------------------- #
     @property
@@ -355,6 +371,7 @@ class FacilityCoordinator:
             # the last rack absorbs the float residual so Σ caps is the
             # facility cap exactly (never above it)
             caps[-1] = max(self.cap_w - math.fsum(caps[:-1]), idle_r[-1])
+        self._due.clear()
         for rack, cap_r in zip(self.racks, caps):
             rack.coord.cap_w = float(cap_r)
             lo, size = rack.offset, rack.size
@@ -362,25 +379,53 @@ class FacilityCoordinator:
                 idle[lo:lo + size], t_min_fn=fn,
                 device_classes=(None if device_classes is None
                                 else list(device_classes[lo:lo + size])))
+        for r in range(self.n_racks):
+            self._refresh(r)
+        self._changed.clear()
+
+    def _refresh(self, r: int) -> None:
+        """Recompute rack ``r``'s inputs to the re-split from its own
+        state: its allocations' exact ``fsum`` and its share weight."""
+        rack = self.racks[r]
+        coord, bw = rack.coord, self.demand_free_weight
+        self._floors[r] = coord.allocated_w
+        if self.share_policy == "tier-weighted":
+            self._weights[r] = rack.size + bw * math.fsum(
+                self._grant_tiers.get(rack.offset + d, 1.0)
+                for d in coord.active_grants())
+        else:
+            # watts follow *absorption capacity*: the engine dispatches
+            # onto free devices, so spare cap belongs where devices are
+            # free to commit it. Weighting by busy devices instead is
+            # actively harmful — a degraded rack's long-running grants
+            # would attract watts it cannot use (its devices are all
+            # leased) while healthy, churning racks starve.
+            self._weights[r] = 1.0 + bw * max(coord.n_free, 0)
 
     def advance(self, t: float) -> None:
+        """Release the grants due by ``t`` and re-split the facility cap.
+        Only racks with a release due are visited, and only racks whose
+        grants or allocations changed since the last call have their
+        re-split inputs recomputed."""
         tr = tracing.ON
         if tr:
             outer = tracing.begin("coord.advance")
             span = tracing.begin("coord.rack_advance")
-        for rack in self.racks:
-            rack.coord.advance(t)
-        multi = self.n_racks > 1
-        if multi and self._grant_tiers:
-            live = set()
-            for rack in self.racks:
-                live.update(rack.offset + d
-                            for d in rack.coord.active_grants())
-            self._grant_tiers = {d: w for d, w in
-                                 self._grant_tiers.items() if d in live}
+        self.stats.advances += 1
+        due, lim = self._due, t + 1e-12
+        while due and due[0][0] <= lim:
+            rack = self.racks[heapq.heappop(due)[1]]
+            for d in rack.coord.advance(t):
+                self._grant_tiers.pop(rack.offset + d, None)
+        changed = self._changed
+        if changed:
+            self.stats.rack_refreshes += len(changed)
+            for r in changed:
+                self._refresh(r)
+            changed.clear()
         if tr:
             tracing.end(span)
-        if (multi and self.share_policy != "static"
+        if (self.n_racks > 1 and self.share_policy != "static"
                 and math.isfinite(self.cap_w)):
             if tr:
                 span = tracing.begin("coord.rebalance")
@@ -395,28 +440,13 @@ class FacilityCoordinator:
         share policy's weights. Each rack's floor is its currently
         allocated watts — committed grants are never clawed back, only
         free cap moves. Σ new caps == facility cap exactly (the last
-        rack takes the float residual, floored at its allocations)."""
-        floors = [r.coord.allocated_w for r in self.racks]
+        rack takes the float residual, floored at its allocations). Reads
+        the per-rack floors and weights ``advance`` keeps current."""
+        floors = self._floors
         dist = self.cap_w - math.fsum(floors)
         if dist < 0.0:
             dist = 0.0
-        bw = self.demand_free_weight
-        if self.share_policy == "demand-weighted":
-            # watts follow *absorption capacity*: the engine dispatches
-            # onto free devices, so spare cap belongs where devices are
-            # free to commit it. Weighting by busy devices instead is
-            # actively harmful — a degraded rack's long-running grants
-            # would attract watts it cannot use (its devices are all
-            # leased) while healthy, churning racks starve.
-            weights = [1.0 + bw * max(
-                rack.size - len(rack.coord.active_grants()), 0)
-                for rack in self.racks]
-        else:  # tier-weighted
-            weights = [
-                rack.size + bw * math.fsum(
-                    self._grant_tiers.get(rack.offset + d, 1.0)
-                    for d in rack.coord.active_grants())
-                for rack in self.racks]
+        weights = self._weights
         total = math.fsum(weights)
         if total <= 0:
             weights = [float(rack.size) for rack in self.racks]

@@ -61,6 +61,7 @@ from __future__ import annotations
 
 import bisect
 import dataclasses
+import heapq
 import math
 from typing import Callable, Iterable, Optional, Sequence
 
@@ -404,6 +405,17 @@ class PowerCapCoordinator:
         #: grant, so a granted-view telemetry ledger reflects the watts
         #: actually *held* and provably never sums above the cap
         self._active: dict[int, tuple[float, float, float, object]] = {}
+        #: min-heap of (end, dev), one entry each time a lease end is set;
+        #: an entry whose end no longer matches ``_active[dev]`` is stale
+        #: and skipped when it surfaces
+        self._ends: list[tuple[float, int]] = []
+        #: ``fsum(_alloc)``, recomputed only after ``_alloc`` changed
+        self._alloc_sum = 0.0
+        self._alloc_stale = True
+        #: (due heap, changed set, key) of a parent coordinator: each lease
+        #: end set is pushed on ``due`` as (end, key), and ``key`` joins
+        #: ``changed`` whenever the allocations or running grants change
+        self._watch: Optional[tuple[list, set, int]] = None
 
     # ------------------------------------------------------------------ #
     @property
@@ -415,8 +427,20 @@ class PowerCapCoordinator:
 
     @property
     def allocated_w(self) -> float:
-        """Σ current allocations (committed grants + idle floors)."""
-        return math.fsum(self._alloc)
+        """Σ current allocations (committed grants + idle floors): the
+        exact ``fsum`` of the allocations, cached until one changes. It is
+        recomputed, never moved by a delta: the fsum of the new list is
+        not the old fsum plus the delta, and the caps derived from it must
+        keep their bits."""
+        if self._alloc_stale:
+            self._alloc_sum = math.fsum(self._alloc)
+            self._alloc_stale = False
+        return self._alloc_sum
+
+    @property
+    def n_free(self) -> int:
+        """Devices holding no running grant."""
+        return len(self._alloc) - len(self._active)
 
     @property
     def headroom_w(self) -> float:
@@ -427,6 +451,24 @@ class PowerCapCoordinator:
         """Snapshot of running grants: dev -> (grant_w, end, drawn_w)."""
         return {d: (g, end, drawn)
                 for d, (g, end, drawn, _) in self._active.items()}
+
+    def watch(self, due: list, changed: set, key: int) -> None:
+        """Report to a parent coordinator: every lease end set from now on
+        is pushed on the heap ``due`` as ``(end, key)``, and ``key`` is
+        added to ``changed`` whenever the allocations or the running
+        grants change — so the parent visits only children with a release
+        due or an input to refresh."""
+        self._watch = (due, changed, key)
+
+    def _changed(self) -> None:
+        self._alloc_stale = True
+        if self._watch is not None:
+            self._watch[1].add(self._watch[2])
+
+    def _lease(self, dev: int, end: float) -> None:
+        heapq.heappush(self._ends, (end, dev))
+        if self._watch is not None:
+            heapq.heappush(self._watch[0], (end, self._watch[2]))
 
     # ------------------------------------------------------------------ #
     def reset(self, idle_powers: Sequence[float],
@@ -448,20 +490,30 @@ class PowerCapCoordinator:
         self._t_min = self.t_min_fn if self.t_min_fn is not None else t_min_fn
         self._alloc = list(self._idle)
         self._active = {}
+        self._ends = []
+        self._changed()
         self.stats = CoordinatorStats()
         if math.isfinite(self.cap_w) and sum(self._idle) > self.cap_w + 1e-9:
             raise ValueError(
                 f"cap {self.cap_w:.1f}W is below the pool's idle floor "
                 f"{sum(self._idle):.1f}W — no schedule can satisfy it")
 
-    def advance(self, t: float) -> None:
+    def advance(self, t: float) -> list[int]:
         """Release grants whose jobs ended at or before ``t`` — their
-        devices revert to the idle floor."""
-        done = [dev for dev, (_, end, _, _) in self._active.items()
-                if end <= t + 1e-12]
-        for dev in done:
-            del self._active[dev]
-            self._alloc[dev] = self._idle[dev]
+        devices revert to the idle floor. Pops only the due entries of the
+        end-time heap; returns the devices released."""
+        ends, lim = self._ends, t + 1e-12
+        done: list[int] = []
+        while ends and ends[0][0] <= lim:
+            end, dev = heapq.heappop(ends)
+            ent = self._active.get(dev)
+            if ent is not None and ent[1] == end:
+                del self._active[dev]
+                self._alloc[dev] = self._idle[dev]
+                done.append(dev)
+        if done:
+            self._changed()
+        return done
 
     # ------------------------------------------------------------------ #
     def _urgency(self, job: Job, start: float,
@@ -489,22 +541,37 @@ class PowerCapCoordinator:
         """Earliest time strictly after ``t`` at which a running grant
         releases — when a deferral can retry with more headroom. None when
         no grant is outstanding (the cluster is as empty as it gets)."""
-        ends = [end for _, end, _, _ in self._active.values()
-                if end > t + 1e-12]
-        return min(ends) if ends else None
+        ends, active = self._ends, self._active
+        while ends:
+            end, dev = ends[0]
+            ent = active.get(dev)
+            if ent is not None and ent[1] == end:
+                break
+            heapq.heappop(ends)                 # stale: skip it for good
+        if not ends:
+            return None
+        if ends[0][0] > t + 1e-12:              # the earliest running end
+            return ends[0][0]
+        # a grant due by `t` is still held (no `advance(t)` yet): scan
+        later = [end for _, end, _, _ in active.values() if end > t + 1e-12]
+        return min(later) if later else None
 
     def _reclaim(self) -> None:
         """Shrink every running grant to ``max(realized draw, idle)`` —
         the granted-but-unused headroom returns to the pool. The attached
         records follow, so they always carry the watts currently held."""
+        shrunk = False
         for d2, (g, end, drawn, rec) in list(self._active.items()):
             keep = max(drawn, self._idle[d2])
             if keep < g - 1e-12:
                 self.stats.reclaimed_w += g - keep
                 self._alloc[d2] = keep
                 self._active[d2] = (keep, end, drawn, rec)
+                shrunk = True
                 if rec is not None:
                     rec.power_grant_w = keep
+        if shrunk:
+            self._changed()
 
     @property
     def reclaimable_w(self) -> float:
@@ -582,8 +649,7 @@ class PowerCapCoordinator:
         # below the fair share (which is what keeps it weakly dominant
         # over uniform at tight caps instead of degenerating to greedy)
         w0 = self._urgency(job, start, dev)
-        n_free_other = sum(1 for d in range(len(self._alloc))
-                           if d not in self._active) - 1
+        n_free_other = self.n_free - 1
         if n_free_other > 0:
             others = sorted((self._urgency(j, start) for _, _, j in queue),
                             reverse=True)[:n_free_other]
@@ -629,6 +695,7 @@ class PowerCapCoordinator:
         if ent is not None:
             g, _, drawn, rec = ent
             self._active[dev] = (g, float(end), drawn, rec)
+            self._lease(dev, float(end))
 
     def escalate(self, dev: int, needed_w: float, start: float) -> float:
         """Deadline rescue: the offered grant blocks a deadline-feasible
@@ -675,6 +742,8 @@ class PowerCapCoordinator:
             self.stats.clamped += 1
         self._alloc[dev] = grant
         self._active[dev] = (grant, float(end), float(drawn_w), record)
+        self._lease(dev, float(end))
+        self._changed()
         if record is not None:
             record.power_grant_w = grant
         self.stats.commits += 1

@@ -483,3 +483,319 @@ class TestFederatedPreemptionUnit:
         class _Job:
             pass
         assert mgr.migration_cost(_Job(), 2) == (0.0, 0.0, None)
+
+
+# ---------------------------------------------------------------------- #
+#  Event-driven grant state ≡ a rescanning reference
+# ---------------------------------------------------------------------- #
+class _RescanRack(PowerCapCoordinator):
+    """A rack coordinator that reads its grant state by rescanning it on
+    every call — a frozen copy of the arithmetic from before the release
+    heap and the cached sums. The event-driven bookkeeping must match it
+    bit for bit."""
+
+    @property
+    def allocated_w(self):
+        return math.fsum(self._alloc)
+
+    def offer(self, dev, job, start, queue=()):
+        self.stats.offers += 1
+        idle_d = self._idle[dev]
+        if not math.isfinite(self.cap_w):
+            return math.inf
+        head = self.headroom_w
+        if self.grant_policy == "uniform":
+            return min(max(self.cap_w / len(self._alloc), idle_d),
+                       idle_d + head)
+        if self.grant_policy == "greedy-edf":
+            return idle_d + head
+        w0 = self._urgency(job, start, dev)
+        n_free_other = sum(1 for d in range(len(self._alloc))
+                           if d not in self._active) - 1
+        if n_free_other > 0:
+            others = sorted((self._urgency(j, start) for _, _, j in queue),
+                            reverse=True)[:n_free_other]
+        else:
+            others = []
+        share = w0 / (w0 + math.fsum(others)) if others else 1.0
+        uniform_w = min(max(self.cap_w / len(self._alloc), idle_d),
+                        idle_d + head)
+        return max(idle_d + head * share, uniform_w)
+
+    def advance(self, t):
+        done = [dev for dev, (_, end, _, _) in self._active.items()
+                if end <= t + 1e-12]
+        for dev in done:
+            del self._active[dev]
+            self._alloc[dev] = self._idle[dev]
+
+    def next_release(self, t):
+        ends = [end for _, end, _, _ in self._active.values()
+                if end > t + 1e-12]
+        return min(ends) if ends else None
+
+
+class _RescanFacility(FacilityCoordinator):
+    """The facility's ``advance`` and ``_rebalance`` as they rescanned
+    every rack on every call, over :class:`_RescanRack` racks."""
+
+    def __init__(self, cap_w, rack_sizes, **kw):
+        super().__init__(cap_w, rack_sizes, **kw)
+        for rack in self.racks:
+            c = rack.coord
+            rack.coord = _RescanRack(c.cap_w, grant_policy=c.grant_policy,
+                                     guard=c.guard, slack_eps=c.slack_eps)
+
+    def advance(self, t):
+        for rack in self.racks:
+            rack.coord.advance(t)
+        multi = self.n_racks > 1
+        if multi and self._grant_tiers:
+            live = set()
+            for rack in self.racks:
+                live.update(rack.offset + d
+                            for d in rack.coord.active_grants())
+            self._grant_tiers = {d: w for d, w in
+                                 self._grant_tiers.items() if d in live}
+        if (multi and self.share_policy != "static"
+                and math.isfinite(self.cap_w)):
+            self._rebalance()
+
+    def _rebalance(self):
+        floors = [r.coord.allocated_w for r in self.racks]
+        dist = self.cap_w - math.fsum(floors)
+        if dist < 0.0:
+            dist = 0.0
+        bw = self.demand_free_weight
+        if self.share_policy == "demand-weighted":
+            weights = [1.0 + bw * max(
+                rack.size - len(rack.coord.active_grants()), 0)
+                for rack in self.racks]
+        else:  # tier-weighted
+            weights = [
+                rack.size + bw * math.fsum(
+                    self._grant_tiers.get(rack.offset + d, 1.0)
+                    for d in rack.coord.active_grants())
+                for rack in self.racks]
+        total = math.fsum(weights)
+        if total <= 0:
+            weights = [float(rack.size) for rack in self.racks]
+            total = math.fsum(weights)
+        caps = [f + dist * w / total for f, w in zip(floors, weights)]
+        caps[-1] = max(self.cap_w - math.fsum(caps[:-1]), floors[-1])
+        for rack, cap_r in zip(self.racks, caps):
+            rack.coord.resize_cap(cap_r)
+        self.stats.rebalances += 1
+
+
+_FACILITY_FIELDS = ("escalations", "rescues", "transfers", "transferred_w",
+                    "rebalances")
+
+
+def _racks_of(c):
+    return [r.coord for r in c.racks] if hasattr(c, "racks") else [c]
+
+
+def _same_state(new, ref, new_recs, ref_recs, t):
+    """Every observable of the grant state, bit for bit."""
+    assert _racks_of(new) and len(_racks_of(new)) == len(_racks_of(ref))
+    for a, b in zip(_racks_of(new), _racks_of(ref)):
+        assert a.cap_w == b.cap_w
+        assert a.allocated_w == b.allocated_w
+        assert a.headroom_w == b.headroom_w
+        assert a.active_grants() == b.active_grants()
+        assert a.stats == b.stats
+    assert new.allocated_w == ref.allocated_w
+    assert new.active_grants() == ref.active_grants()
+    for q in (t, t + 0.7, t - 0.3):
+        assert new.next_release(q) == ref.next_release(q)
+    assert ([r.power_grant_w for r in new_recs]
+            == [r.power_grant_w for r in ref_recs])
+    if hasattr(new, "racks"):
+        assert new.caps() == ref.caps()
+        for f in _FACILITY_FIELDS:
+            assert getattr(new.stats, f) == getattr(ref.stats, f), f
+
+
+def _drive(new, ref, n_dev, seed, steps=300):
+    """A seeded random sequence of every call that touches the grant state
+    (commit, advance, truncate, escalate, release_cap, reclaim_unused,
+    offer, potential_w), applied to both coordinators, with every
+    observable compared after every step. Ends fall on a 0.25 s grid half
+    the time, so releases tie."""
+    from types import SimpleNamespace
+
+    from repro.core.workload import TIERS
+
+    rng = np.random.default_rng(seed)
+    idle = [float(x) for x in rng.uniform(15.0, 60.0, n_dev)]
+    new.reset(idle)
+    ref.reset(idle)
+    tiers = list(TIERS.values())
+    new_recs, ref_recs = [], []
+    t = 0.0
+
+    def end_after(t0):
+        dt = float(rng.uniform(0.05, 4.0))
+        return t0 + (round(dt * 4) / 4 or 0.25 if rng.random() < 0.5
+                     else dt)
+
+    def job():
+        return SimpleNamespace(deadline=t + float(rng.uniform(0.5, 6.0)),
+                               tier=tiers[int(rng.integers(len(tiers)))])
+
+    counts: dict[str, int] = {}
+    for _ in range(steps):
+        op = str(rng.choice(["commit", "commit", "commit", "advance",
+                             "advance", "truncate", "escalate",
+                             "release_cap", "reclaim", "offer"]))
+        counts[op] = counts.get(op, 0) + 1
+        dev = int(rng.integers(n_dev))
+        if op == "commit":
+            req = idle[dev] + float(rng.uniform(0.0, 250.0))
+            drawn = req * float(rng.uniform(0.6, 1.1))
+            end = end_after(t)
+            tier = tiers[int(rng.integers(len(tiers)))].name
+            # a quarter of a facility's commits go to the rack directly:
+            # the facility still learns of the lease, and its tier weight
+            # is the default one
+            direct = hasattr(new, "racks") and rng.random() < 0.25
+            for c, recs in ((new, new_recs), (ref, ref_recs)):
+                rec = SimpleNamespace(power_grant_w=None, tier=tier)
+                recs.append(rec)
+                if direct:
+                    rack = c.racks[c.rack_of(dev)]
+                    rack.coord.commit(rack.local(dev), req, end, drawn,
+                                      record=rec)
+                else:
+                    c.commit(dev, req, end, drawn, record=rec)
+        elif op == "advance":
+            pending = list(new.active_grants().values())
+            if pending and rng.random() < 0.3:
+                # land on a grant's end, or a hair before it
+                end = pending[int(rng.integers(len(pending)))][1]
+                t = max(t, end - float(rng.choice([0.0, 1e-13, 1e-9])))
+            else:
+                t += float(rng.choice([0.0, rng.uniform(0.0, 1.5)]))
+            new.advance(t)
+            ref.advance(t)
+        elif op == "truncate":
+            grants = new.active_grants()
+            if grants and rng.random() < 0.8:
+                dev = sorted(grants)[int(rng.integers(len(grants)))]
+            end = t + float(rng.uniform(0.0, 1.0))
+            new.truncate(dev, end)
+            ref.truncate(dev, end)
+        elif op == "escalate":
+            need = idle[dev] + float(rng.uniform(0.0, 400.0))
+            assert new.escalate(dev, need, t) == ref.escalate(dev, need, t)
+        elif op in ("release_cap", "reclaim"):
+            r = int(rng.integers(len(_racks_of(new))))
+            a, b = _racks_of(new)[r], _racks_of(ref)[r]
+            if op == "reclaim":
+                assert a.reclaim_unused() == b.reclaim_unused()
+            else:
+                w = float(rng.uniform(0.0, 200.0))
+                assert a.release_cap(w) == b.release_cap(w)
+        else:
+            j = job()
+            queue = [(0, i, job()) for i in range(int(rng.integers(6)))]
+            assert new.offer(dev, j, t, queue) == ref.offer(dev, j, t, queue)
+            assert new.potential_w(dev) == ref.potential_w(dev)
+        _same_state(new, ref, new_recs, ref_recs, t)
+    return counts
+
+
+class TestEventDrivenGrantState:
+    """The release heap and the cached per-rack sums and counts give the
+    same caps, grants and stats as rescanning every rack on every call."""
+
+    @pytest.mark.parametrize("n_racks", [1, 8, 32])
+    @pytest.mark.parametrize("share", FACILITY_SHARE_POLICIES)
+    def test_facility_matches_rescanning_reference(self, share, n_racks):
+        for seed in range(3):
+            grant = GRANT_POLICIES[seed % len(GRANT_POLICIES)]
+            size = 4 if n_racks < 32 else 2
+            n_dev = size * n_racks
+            # from a cap that binds hard to one that never does
+            cap = 60.0 * n_dev + (30.0, 90.0, 400.0)[seed] * n_dev
+            kw = dict(share_policy=share, grant_policy=grant)
+            new = FacilityCoordinator(cap, [size] * n_racks, **kw)
+            ref = _RescanFacility(cap, [size] * n_racks, **kw)
+            counts = _drive(new, ref, n_dev, seed=100 * n_racks + seed)
+            assert min(counts.values()) > 0, counts
+            if n_racks > 1 and share != "static":
+                assert new.stats.rebalances == counts["advance"]
+
+    @pytest.mark.parametrize("grant", GRANT_POLICIES)
+    def test_bare_coordinator_matches_rescanning_reference(self, grant):
+        for seed in range(3):
+            cap = (300.0, 600.0, math.inf)[seed]
+            new = PowerCapCoordinator(cap, grant_policy=grant)
+            ref = _RescanRack(cap, grant_policy=grant)
+            _drive(new, ref, 6, seed=seed)
+
+    @pytest.mark.parametrize("preempt", [False, True])
+    @pytest.mark.parametrize("share", FACILITY_SHARE_POLICIES)
+    def test_engine_schedule_matches_rescanning_reference(
+            self, share, preempt, testbed, fitted, app_feats):
+        """32 racks of 2 under a cap that binds: the same records, grants
+        and stats as the rescanning coordinator, on the plain loop and on
+        the preemptive loop (where preemption truncates leases)."""
+        n_dev, racks = 64, [2] * 32
+        jobs = list(multi_rack_workload(APPS, testbed, n_devices=n_dev,
+                                        n_jobs=240, seed=3))
+        cap = 64 * testbed.idle_power() + 40.0 * n_dev
+        out = []
+        for cls in (FacilityCoordinator, _RescanFacility):
+            fac = cls(cap, racks, share_policy=share)
+            pre = (FederatedPreemptionManager(
+                racks, dvfs=V5E_DVFS, device_slowdown={3: 4.0, 40: 3.0})
+                if preempt else None)
+            r = run_schedule(jobs, "risk-aware", Testbed(seed=1000),
+                             predictor=fitted, app_features=app_feats,
+                             n_devices=n_dev, power_coordinator=fac,
+                             preemption=pre)
+            out.append((fac, r))
+        (new, r1), (ref, r2) = out
+        assert len(r1.records) == len(r2.records) > 0
+        for a, b in zip(r1.records, r2.records):
+            assert (a.job_id, a.device, a.clock, a.start, a.end,
+                    a.power_grant_w) == (b.job_id, b.device, b.clock,
+                                         b.start, b.end, b.power_grant_w)
+        assert new.caps() == ref.caps()
+        assert new.rack_stats() == ref.rack_stats()
+        for f in _FACILITY_FIELDS:
+            assert getattr(new.stats, f) == getattr(ref.stats, f), f
+        assert new.stats.escalations > 0       # the cap binds
+        if preempt:
+            assert any(rec.preempted for rec in r1.records)
+
+    def test_counters_count_calls_and_changed_racks(self):
+        fac = FacilityCoordinator(1000.0, [2, 2, 2, 2])
+        fac.reset([20.0] * 8)
+        visited = []
+        for rack in fac.racks:
+            def spy(t, i=rack.index, advance=rack.coord.advance):
+                visited.append(i)
+                return advance(t)
+            rack.coord.advance = spy
+        fac.advance(0.0)                   # nothing changed since reset
+        assert (fac.stats.advances, fac.stats.rack_refreshes) == (1, 0)
+        fac.commit(0, 100.0, end=5.0, drawn_w=90.0)     # rack 0
+        fac.commit(5, 100.0, end=3.0, drawn_w=90.0)     # rack 2
+        fac.commit(4, 100.0, end=8.0, drawn_w=90.0)     # rack 2
+        fac.advance(1.0)
+        assert fac.stats.rack_refreshes == 2
+        fac.advance(2.0)
+        fac.truncate(0, 4.0)               # a lease moves, no watts do
+        fac.advance(3.5)                   # rack 2 releases device 5
+        assert fac.stats.rack_refreshes == 3
+        fac.advance(4.0)                   # rack 0 releases device 0
+        assert (fac.stats.advances, fac.stats.rack_refreshes) == (5, 4)
+        # only racks with a lease end due were ever visited: rack 2 once,
+        # rack 0 at the truncated end and at its stale original end
+        fac.advance(10.0)
+        assert sorted(set(visited)) == [0, 2]
+        assert (fac.stats.advances, fac.stats.rack_refreshes) == (6, 5)
+        assert fac.active_grants() == {}
